@@ -119,6 +119,8 @@ def _worker(quick: bool) -> None:
             "merged_vs_halo_plan": round(
                 median_ratio(acc["halo-plan"], acc["halo-plan-merged"]), 2),
         })
+    for r in records:
+        r["platform"] = jax.default_backend()
     print(MARKER + json.dumps(records))
 
 
@@ -128,6 +130,9 @@ def run(out_rows: List[str], records: Optional[List[Dict]] = None) -> None:
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(root, "src") + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    # fake host devices by design: the child never reaches for a chip
+    # (which its parent may hold)
+    env["JAX_PLATFORMS"] = "cpu"
     cmd = [sys.executable, "-m", "benchmarks.dist_bench", "--worker"]
     if quick:
         cmd.append("--quick")

@@ -25,6 +25,7 @@ import json
 import os
 import sys
 import traceback
+from pathlib import Path
 from typing import Dict, List, Optional
 
 MODULES = ["accuracy", "hgemv", "compression_bench", "construction_bench",
@@ -119,6 +120,8 @@ def main() -> None:
                     help="previous-run records to diff against; >20%% "
                          "per-key regressions print non-fatal # WARN rows")
     args, _ = ap.parse_known_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache(Path(__file__).resolve().parents[1])
     if args.quick:
         os.environ["REPRO_BENCH_QUICK"] = "1"
     mods = args.only.split(",") if args.only else MODULES

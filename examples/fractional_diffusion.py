@@ -10,9 +10,11 @@ dimension-independent convergence.
 """
 import argparse
 import time
+from pathlib import Path
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.apps.fractional import solve, dense_reference_solution
 
 
@@ -41,4 +43,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache(Path(__file__).resolve().parents[1])
     main()

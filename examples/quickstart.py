@@ -2,9 +2,12 @@
 
     PYTHONPATH=src python examples/quickstart.py
 """
+from pathlib import Path
+
 import numpy as np
 import jax.numpy as jnp
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core.clustering import regular_grid_points
 from repro.core.construction import construct_h2, dense_reference
 from repro.core.kernels_fn import exponential_kernel
@@ -42,4 +45,5 @@ def main(side: int = 64, leaf_size: int = 64):
 
 
 if __name__ == "__main__":
+    enable_compile_cache(Path(__file__).resolve().parents[1])
     main()

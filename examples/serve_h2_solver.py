@@ -16,9 +16,11 @@ injection plan.
 """
 import argparse
 import time
+from pathlib import Path
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.core.clustering import regular_grid_points
 from repro.core.compression import compress
 from repro.core.construction import construct_h2
@@ -97,6 +99,7 @@ def main(side: int = 64, leaf_size: int = 64, tol: float = 1e-6,
 
 
 if __name__ == "__main__":
+    enable_compile_cache(Path(__file__).resolve().parents[1])
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--side", type=int, default=64)
     ap.add_argument("--leaf-size", type=int, default=64)

@@ -7,9 +7,11 @@ PowerSGD gradient compression enabled.
 """
 import argparse
 import tempfile
+from pathlib import Path
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.configs.base import get_config
 from repro.launch.train import train
 from repro.runtime.fault import FailureInjector
@@ -46,4 +48,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache(Path(__file__).resolve().parents[1])
     main()

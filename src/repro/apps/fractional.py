@@ -34,7 +34,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.clustering import build_cluster_tree
 from repro.core.construction import construct_h2
 from repro.core.compression import compress
@@ -467,10 +466,10 @@ def make_dist_solve(prob: Dict, mesh: Mesh, axis="blk",
                           maxiter=maxiter, axis=axis)
         raise ValueError(f"unknown method {method!r}")
 
-    fn = jax.jit(shard_map(local, mesh=mesh,
-                           in_specs=(*specs, P(axis)),
-                           out_specs=result_specs(P(axis)),
-                           check_vma=False))
+    fn = jax.jit(jax.shard_map(local, mesh=mesh,
+                               in_specs=(*specs, P(axis)),
+                               out_specs=result_specs(P(axis)),
+                               check_vma=False))
 
     def place(tree, tree_specs=specs):
         return jax.tree.map(
@@ -582,18 +581,18 @@ def make_dist_solve_segment(prob: Dict, mesh: Mesh, axis="blk",
         st = pcg_init(apply_a, b, pre, x0=state.x, axis=axis)
         return dataclasses.replace(st, k=state.k)
 
-    init = jax.jit(shard_map(init_local, mesh=mesh,
-                             in_specs=(*specs, P(axis)),
-                             out_specs=sspecs, check_vma=False))
-    segment = jax.jit(shard_map(seg_local, mesh=mesh,
-                                in_specs=(*specs, P(axis), sspecs),
-                                out_specs=sspecs, check_vma=False))
-    residual = jax.jit(shard_map(res_local, mesh=mesh,
-                                 in_specs=(*specs, P(axis), sspecs),
-                                 out_specs=(P(), P()), check_vma=False))
-    rebaseline = jax.jit(shard_map(rebase_local, mesh=mesh,
-                                   in_specs=(*specs, P(axis), sspecs),
-                                   out_specs=sspecs, check_vma=False))
+    init = jax.jit(jax.shard_map(init_local, mesh=mesh,
+                                 in_specs=(*specs, P(axis)),
+                                 out_specs=sspecs, check_vma=False))
+    segment = jax.jit(jax.shard_map(seg_local, mesh=mesh,
+                                    in_specs=(*specs, P(axis), sspecs),
+                                    out_specs=sspecs, check_vma=False))
+    residual = jax.jit(jax.shard_map(res_local, mesh=mesh,
+                                     in_specs=(*specs, P(axis), sspecs),
+                                     out_specs=(P(), P()), check_vma=False))
+    rebaseline = jax.jit(jax.shard_map(rebase_local, mesh=mesh,
+                                       in_specs=(*specs, P(axis), sspecs),
+                                       out_specs=sspecs, check_vma=False))
 
     def place(tree, tree_specs=specs):
         return jax.tree.map(

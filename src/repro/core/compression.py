@@ -104,7 +104,8 @@ def compression_weights(shape: H2Shape, data: H2Data, backend: str = "jnp",
             kl, kp = ranks[l], ranks[l - 1]
             # parent part: R_parent @ E_c^T -> [2**l, k_{l-1}, k_l]
             rpar = jnp.repeat(r[l - 1], 2, axis=0)
-            par = jnp.einsum("cij,ckj->cik", rpar, transfers[l])
+            par = jnp.einsum("cij,ckj->cik", rpar, transfers[l],
+                             precision="highest")
             pieces = [par]
             if shape.coupling_counts[l] > 0 and maxb_tuple[l] > 0:
                 pieces.append(stacked_fn(l))        # [nn, maxb*k_l, k_l]
@@ -163,17 +164,17 @@ def truncation_inner_factors(p: jax.Array, transfer: jax.Array,
 
     Returns (stack [nn/2, 2r_l, k_{l-1}], basis g, svals).
     """
-    pe = jnp.einsum("crk,ckp->crp", p, transfer)
+    pe = jnp.einsum("crk,ckp->crp", p, transfer, precision="highest")
     rl = pe.shape[1]
     stack = pe.reshape(pe.shape[0] // 2, 2 * rl, -1)
-    m = jnp.einsum("nik,njk->nij", stack, r_parent)
+    m = jnp.einsum("nik,njk->nij", stack, r_parent, precision="highest")
     g, s, _ = _batched_svd(m, backend)
     return stack, g, s
 
 
 def truncation_project(gk: jax.Array, stack: jax.Array) -> jax.Array:
     """Next level's projection map ``P_{l-1} = G_k^T stack``."""
-    return jnp.einsum("nir,nik->nrk", gk, stack)
+    return jnp.einsum("nir,nik->nrk", gk, stack, precision="highest")
 
 
 def _project_couplings(shape: H2Shape, data: H2Data, pu: List[jax.Array],
@@ -188,7 +189,8 @@ def _project_couplings(shape: H2Shape, data: H2Data, pu: List[jax.Array],
                 continue
             pl = jnp.take(pu[l], data.s_rows[l], axis=0)  # [nb, r, k]
             pr = jnp.take(pv[l], data.s_cols[l], axis=0)
-            s_new.append(jnp.einsum("brk,bkj,bsj->brs", pl, data.s[l], pr))
+            s_new.append(jnp.einsum("brk,bkj,bsj->brs", pl, data.s[l], pr,
+                                    precision="highest"))
     return s_new
 
 
@@ -232,7 +234,7 @@ def truncate(shape: H2Shape, data: H2Data, ru: List[jax.Array],
         w, _ = truncation_leaf_factors(r[depth], backend)
         rq = min(tr[depth], w.shape[-1])
         wk = w[..., :rq]                                  # [nl, k, r]
-        new_leaf = jnp.einsum("nmk,nkr->nmr", leaf, wk)
+        new_leaf = jnp.einsum("nmk,nkr->nmr", leaf, wk, precision="highest")
         p[depth] = jnp.swapaxes(wk, -1, -2)               # [nl, r, k]
         for l in range(depth, 0, -1):
             nn = shape.nodes(l)
@@ -265,7 +267,8 @@ _inner_factors_jit = jax.jit(truncation_inner_factors,
 @functools.partial(jax.jit, static_argnames=("rq",))
 def _leaf_apply_jit(leaf: jax.Array, w: jax.Array, rq: int):
     wk = w[..., :rq]
-    return jnp.einsum("nmk,nkr->nmr", leaf, wk), jnp.swapaxes(wk, -1, -2)
+    new_leaf = jnp.einsum("nmk,nkr->nmr", leaf, wk, precision="highest")
+    return new_leaf, jnp.swapaxes(wk, -1, -2)
 
 
 @functools.partial(jax.jit, static_argnames=("rp", "nn"))

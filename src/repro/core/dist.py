@@ -40,7 +40,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.obs.trace import phase
 
 from . import halo as _halo
@@ -329,10 +328,12 @@ def _local_upsweep(dshape: DistH2Shape, d: DistH2Data, x_leaves, axis):
     depth, lc = dshape.depth, dshape.lc
     with phase("hgemv/upsweep"):
         xhat: Dict[int, jax.Array] = {}
-        xhat[depth] = jnp.einsum("bmk,bmv->bkv", d.v_leaf, x_leaves)
+        xhat[depth] = jnp.einsum("bmk,bmv->bkv", d.v_leaf, x_leaves,
+                                 precision="highest")
         for l in range(depth, lc, -1):
             f = d.f_br[l - lc]
-            contrib = jnp.einsum("ckp,ckv->cpv", f, xhat[l])
+            contrib = jnp.einsum("ckp,ckv->cpv", f, xhat[l],
+                                 precision="highest")
             nn = contrib.shape[0]
             xhat[l - 1] = contrib.reshape(nn // 2, 2,
                                           *contrib.shape[1:]).sum(1)
@@ -343,7 +344,8 @@ def _local_upsweep(dshape: DistH2Shape, d: DistH2Data, x_leaves, axis):
         xhat_top: Dict[int, jax.Array] = {lc: gathered}  # [2**lc, k, nv]
         for l in range(lc, 0, -1):
             f = d.f_top[l]
-            contrib = jnp.einsum("ckp,ckv->cpv", f, xhat_top[l])
+            contrib = jnp.einsum("ckp,ckv->cpv", f, xhat_top[l],
+                                 precision="highest")
             nn = contrib.shape[0]
             xhat_top[l - 1] = contrib.reshape(nn // 2, 2,
                                               *contrib.shape[1:]).sum(1)
@@ -401,7 +403,8 @@ def _coupling_phase(dshape: DistH2Shape, d: DistH2Data, xhat, xhat_top,
             xg = jnp.take(halo, idx, axis=0).astype(xhat[l].dtype)
         with phase("hgemv/coupling-gemm"):
             yhat[l] = jnp.einsum("nkj,njv->nkv", s_mar,
-                                 xg.reshape(nloc, maxb * k, nv))
+                                 xg.reshape(nloc, maxb * k, nv),
+                                 precision="highest")
 
     with phase("hgemv/coupling-gemm"):
         _top_coupling(dshape, d, xhat_top, yhat_top, nv)
@@ -421,7 +424,8 @@ def _top_coupling(dshape: DistH2Shape, d: DistH2Data, xhat_top, yhat_top,
         maxb = s_mar.shape[-1] // k
         xg = jnp.take(xhat_top[l], d.pt_col[l], axis=0)
         yhat_top[l] = jnp.einsum("nkj,njv->nkv", s_mar,
-                                 xg.reshape(nn, maxb * k, nv))
+                                 xg.reshape(nn, maxb * k, nv),
+                                 precision="highest")
 
 
 def _use_split(schedule: str, nloc: int, maxb: int, maxb_d: int,
@@ -682,7 +686,8 @@ def _coupling_phase_overlap(dshape: DistH2Shape, d: DistH2Data, xhat,
                 maxb = s_mar.shape[-1] // k
                 xg = jnp.take(xhat_top[lc], d.pb_col[i], axis=0)
                 yhat[l] = jnp.einsum("nkj,njv->nkv", s_mar,
-                                     xg.reshape(nloc, maxb * k, nv))
+                                     xg.reshape(nloc, maxb * k, nv),
+                                     precision="highest")
                 continue
             if not _split(i, k):
                 yhat[l] = None
@@ -691,14 +696,16 @@ def _coupling_phase_overlap(dshape: DistH2Shape, d: DistH2Data, xhat,
             maxb_d = s_diag.shape[-1] // k
             xg = jnp.take(xhat[l], d.hp_br[i].diag_col, axis=0)
             yhat[l] = jnp.einsum("nkj,njv->nkv", s_diag,
-                                 xg.reshape(nloc, maxb_d * k, nv))
+                                 xg.reshape(nloc, maxb_d * k, nv),
+                                 precision="highest")
         y_de = None
         if d_split:
             d_diag = d.dense_mar_diag          # [nl, m, dmaxb_d*m]
             dmaxb_d = d_diag.shape[-1] // m
             xg = jnp.take(x_leaves, d.hp_dense.diag_col, axis=0)
             y_de = jnp.einsum("nkj,njv->nkv", d_diag,
-                              xg.reshape(nl, dmaxb_d * m, nv))
+                              xg.reshape(nl, dmaxb_d * m, nv),
+                              precision="highest")
         _top_coupling(dshape, d, xhat_top, yhat_top, nv)
 
     # --- phase C: finish from the landed buffers.  Split levels add the
@@ -714,7 +721,8 @@ def _coupling_phase_overlap(dshape: DistH2Shape, d: DistH2Data, xhat,
         buf = _landed(src, key, offsets, caps, width)
         xg = jnp.take(buf, plan.off_idx, axis=0)
         off = jnp.einsum("nkj,njv->nkv", s_off,
-                         xg.reshape(s_off.shape[0], maxb_o * width, nv))
+                         xg.reshape(s_off.shape[0], maxb_o * width, nv),
+                         precision="highest")
         corrected = jnp.take(y, plan.bnd_rows, axis=0) + off
         return jnp.take(jnp.concatenate([y, corrected], axis=0),
                         plan.rowpos, axis=0)
@@ -726,7 +734,8 @@ def _coupling_phase_overlap(dshape: DistH2Shape, d: DistH2Data, xhat,
         buf = _landed(src, key, offsets, caps, width) if p > 1 else src
         xg = jnp.take(buf, plan.comb_idx, axis=0)
         return jnp.einsum("nkj,njv->nkv", s_mar,
-                          xg.reshape(rows, maxb * width, nv))
+                          xg.reshape(rows, maxb * width, nv),
+                          precision="highest")
 
     with phase("hgemv/off-gemm"):
         for l in range(lc, depth + 1):
@@ -765,7 +774,8 @@ def _local_downsweep(dshape: DistH2Shape, d: DistH2Data, yhat, yhat_top,
             acc = yhat_top[0]
             for l in range(1, lc + 1):
                 par = jnp.repeat(acc, 2, axis=0)
-                step = jnp.einsum("ckp,cpv->ckv", d.e_top[l], par)
+                step = jnp.einsum("ckp,cpv->ckv", d.e_top[l], par,
+                                  precision="highest")
                 add = yhat_top[l] if l < lc else 0.0
                 acc = step + add
             own = jax.lax.dynamic_slice_in_dim(acc, me, 1, axis=0)
@@ -774,8 +784,9 @@ def _local_downsweep(dshape: DistH2Shape, d: DistH2Data, yhat, yhat_top,
             acc = yhat[lc]
         for l in range(lc + 1, depth + 1):
             par = jnp.repeat(acc, 2, axis=0)
-            acc = yhat[l] + jnp.einsum("ckp,cpv->ckv", d.e_br[l - lc], par)
-        return jnp.einsum("bmk,bkv->bmv", d.u_leaf, acc)
+            acc = yhat[l] + jnp.einsum("ckp,cpv->ckv", d.e_br[l - lc], par,
+                                       precision="highest")
+        return jnp.einsum("bmk,bkv->bmv", d.u_leaf, acc, precision="highest")
 
 
 def _dense_phase(dshape: DistH2Shape, d: DistH2Data, x_leaves, axis,
@@ -808,7 +819,7 @@ def _dense_phase(dshape: DistH2Shape, d: DistH2Data, x_leaves, axis,
             xg = jnp.take(halo, idx, axis=0).astype(x_leaves.dtype)
     with phase("hgemv/dense"):
         return jnp.einsum("nkj,njv->nkv", d_mar,
-                          xg.reshape(nloc, dmaxb * m, nv))
+                          xg.reshape(nloc, dmaxb * m, nv), precision="highest")
 
 
 def dist_h2_matvec_local(dshape: DistH2Shape, d: DistH2Data, x: jax.Array,
@@ -861,7 +872,7 @@ def make_dist_matvec(dshape: DistH2Shape, mesh: Mesh, axis,
         return dist_h2_matvec_local(dshape, d, x, axis, comm, backend,
                                     schedule, hide_flops)
 
-    shmapped = shard_map(
+    shmapped = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(specs, xspec),
         out_specs=xspec,
@@ -882,7 +893,7 @@ def _branch_orthogonalize(dshape: DistH2Shape, leaf, e_br, e_top, axis):
     new_e_br = [e_br[0]] + [None] * (depth - lc)
     for l in range(depth, lc, -1):
         e = e_br[l - lc]
-        re = jnp.einsum("crk,ckp->crp", r[l], e)
+        re = jnp.einsum("crk,ckp->crp", r[l], e, precision="highest")
         nn, kl, kp = re.shape
         stacked = re.reshape(nn // 2, 2 * kl, kp)
         q, rr = jnp.linalg.qr(stacked, mode="reduced")
@@ -894,7 +905,7 @@ def _branch_orthogonalize(dshape: DistH2Shape, leaf, e_br, e_top, axis):
     new_e_top = [e_top[0]] + [None] * lc
     for l in range(lc, 0, -1):
         e = e_top[l]
-        re = jnp.einsum("crk,ckp->crp", r_top[l], e)
+        re = jnp.einsum("crk,ckp->crp", r_top[l], e, precision="highest")
         nn, kl, kp = re.shape
         stacked = re.reshape(nn // 2, 2 * kl, kp)
         q, rr = jnp.linalg.qr(stacked, mode="reduced")
@@ -931,14 +942,15 @@ def dist_orthogonalize_local(dshape: DistH2Shape, d: DistH2Data, axis
             r_cols = jnp.take(buf, d.hp_br[i].blk_idx, axis=0)
         r_rows = jnp.take(rl, d.s_br_rows[i], axis=0)
         s_br_new.append(jnp.einsum("bij,bjk,blk->bil", r_rows, d.s_br[i],
-                                   r_cols))
+                                   r_cols, precision="highest"))
     for l in range(lc):
         if dshape.top_counts[l] == 0:
             s_top_new.append(d.s_top[l])
             continue
         rr = jnp.take(r_top[l], d.s_top_rows[l], axis=0)
         rc = jnp.take(r_top[l], d.s_top_cols[l], axis=0)
-        s_top_new.append(jnp.einsum("bij,bjk,blk->bil", rr, d.s_top[l], rc))
+        s_top_new.append(jnp.einsum("bij,bjk,blk->bil", rr, d.s_top[l], rc,
+                                    precision="highest"))
 
     return _with_remarshaled(dshape, d, DistH2Data(
         u_leaf=q_leaf, v_leaf=q_leaf,
@@ -1011,7 +1023,7 @@ def dist_compress_local(dshape: DistH2Shape, d: DistH2Data,
         nn = 1 << l
         kl, kp = ranks[l], ranks[l - 1]
         rpar = jnp.repeat(w_top[l - 1], 2, axis=0)
-        par = jnp.einsum("cij,ckj->cik", rpar, d.e_top[l])
+        par = jnp.einsum("cij,ckj->cik", rpar, d.e_top[l], precision="highest")
         pieces = [par]
         if l < lc and dshape.top_counts[l] > 0:
             st = jnp.swapaxes(d.s_top[l], -1, -2)
@@ -1037,7 +1049,8 @@ def dist_compress_local(dshape: DistH2Shape, d: DistH2Data,
             par_r = jax.lax.dynamic_slice_in_dim(par_r, me * nloc, nloc, 0)
             par = jnp.einsum("cij,ckj->cik", par_r,
                              jax.lax.dynamic_slice_in_dim(
-                                 d.e_top[lc], me * nloc, nloc, 0))
+                                 d.e_top[lc], me * nloc, nloc, 0),
+                             precision="highest")
             pieces = [par]
         else:
             pieces = [jnp.zeros((nloc, ranks[0], kl), d.u_leaf.dtype)]
@@ -1055,7 +1068,7 @@ def dist_compress_local(dshape: DistH2Shape, d: DistH2Data,
         nloc = dshape.nodes_local(l)
         kl = ranks[l]
         rpar = jnp.repeat(w[l - 1], 2, axis=0)
-        par = jnp.einsum("cij,ckj->cik", rpar, d.e_br[i])
+        par = jnp.einsum("cij,ckj->cik", rpar, d.e_br[i], precision="highest")
         pieces = [par]
         if dshape.br_counts[i] > 0:
             st = jnp.swapaxes(d.s_br[i], -1, -2)
@@ -1076,7 +1089,7 @@ def dist_compress_local(dshape: DistH2Shape, d: DistH2Data,
     wq, _ = truncation_leaf_factors(w[depth])
     rq = min(tr[depth], wq.shape[-1])
     wk = wq[..., :rq]
-    new_leaf = jnp.einsum("nmk,nkr->nmr", d.u_leaf, wk)
+    new_leaf = jnp.einsum("nmk,nkr->nmr", d.u_leaf, wk, precision="highest")
     pmap_: Dict[int, jax.Array] = {depth: jnp.swapaxes(wk, -1, -2)}
     new_e_br = [d.e_br[0]] + [None] * (depth - lc)
     for l in range(depth, lc, -1):
@@ -1113,7 +1126,8 @@ def dist_compress_local(dshape: DistH2Shape, d: DistH2Data,
                                  axis, p) if p > 1 else pl_
             pc = jnp.take(buf, d.hp_br[i].blk_idx, axis=0)
         pr = jnp.take(pl_, d.s_br_rows[i], axis=0)
-        s_br_new.append(jnp.einsum("brk,bkj,bsj->brs", pr, d.s_br[i], pc))
+        s_br_new.append(jnp.einsum("brk,bkj,bsj->brs", pr, d.s_br[i], pc,
+                                   precision="highest"))
     for l in range(lc):
         if dshape.top_counts[l] == 0:
             nb = d.s_top[l].shape[0]
@@ -1122,7 +1136,8 @@ def dist_compress_local(dshape: DistH2Shape, d: DistH2Data,
             continue
         pr = jnp.take(p_top[l], d.s_top_rows[l], axis=0)
         pc = jnp.take(p_top[l], d.s_top_cols[l], axis=0)
-        s_top_new.append(jnp.einsum("brk,bkj,bsj->brs", pr, d.s_top[l], pc))
+        s_top_new.append(jnp.einsum("brk,bkj,bsj->brs", pr, d.s_top[l], pc,
+                                    precision="highest"))
 
     return _with_remarshaled(dshape, d, DistH2Data(
         u_leaf=new_leaf, v_leaf=new_leaf,
@@ -1148,7 +1163,7 @@ def make_dist_compress(dshape: DistH2Shape, mesh: Mesh, axis,
 
     out_specs = dist_specs(
         dataclasses.replace(dshape, ranks=tuple(target_ranks)), axis)
-    shmapped = shard_map(fn, mesh=mesh, in_specs=(specs,),
+    shmapped = jax.shard_map(fn, mesh=mesh, in_specs=(specs,),
                              out_specs=out_specs, check_vma=False)
     return jax.jit(shmapped)
 
@@ -1162,15 +1177,16 @@ def matvec_comm_bytes(dshape: DistH2Shape, nv: int, comm: str = "halo-plan",
     """Per-device collective bytes of one distributed matvec.
 
     ``allgather`` ships ``(p-1)`` full level copies and broadcast
-    ``ppermute`` ``2*rad`` copies.  ``halo-plan`` ships only the
+    ``ppermute`` one copy per distinct shift: ``2*rad`` of them, or
+    ``p-1`` once ``2*rad >= p`` makes shifts ``d`` and ``d-p`` name the
+    same peers (the compiler merges those).  ``halo-plan`` ships only the
     compressed send lists — ``sum(caps)`` rows per level, the paper's
     §4.1 volume.  The branch-root gather is a tiled ``all_gather``: each
-    device receives the other ``p-1`` slices (its own it already holds).
-    ``-bf16`` payload modes halve ``bytes_per_el`` at the call site.
+    device receives the other ``p-1`` slices (its own it already holds;
+    ``root_gather_bytes``).  ``-bf16`` payload modes halve
+    ``bytes_per_el`` at the call site.
     """
-    total = 0
-    k_lc = dshape.ranks[dshape.lc]
-    total += (dshape.p - 1) * k_lc * nv * bytes_per_el    # branch-root gather
+    total = root_gather_bytes(dshape, nv, comm, bytes_per_el)
     for l in range(dshape.lc, dshape.depth + 1):
         i = l - dshape.lc
         nloc = dshape.nodes_local(l)
@@ -1181,7 +1197,7 @@ def matvec_comm_bytes(dshape: DistH2Shape, nv: int, comm: str = "halo-plan",
             if l > dshape.lc:      # level lc rides the branch-root gather
                 total += sum(dshape.br_caps[i]) * row
         else:
-            total += 2 * dshape.br_radius[i] * nloc * row
+            total += _shifts(dshape.br_radius[i], dshape.p) * nloc * row
     nl = dshape.leaves_per_dev
     row = dshape.leaf_size * nv * bytes_per_el
     if comm == "allgather":
@@ -1189,8 +1205,25 @@ def matvec_comm_bytes(dshape: DistH2Shape, nv: int, comm: str = "halo-plan",
     elif comm.startswith("halo-plan"):
         total += sum(dshape.dense_caps) * row
     else:
-        total += 2 * dshape.dense_radius * nl * row
+        total += _shifts(dshape.dense_radius, dshape.p) * nl * row
     return total
+
+
+def root_gather_bytes(dshape: DistH2Shape, nv: int, comm: str = "halo-plan",
+                      bytes_per_el: int = 4) -> int:
+    """Per-device bytes of the branch-root ``all_gather``, where something
+    reads it: level lc under halo-plan, the top tree wherever it holds
+    coupling blocks.  Otherwise the compiled program drops it, and under
+    ``allgather`` it is the same collective as level lc's own gather."""
+    if comm.startswith("halo-plan") or (comm != "allgather"
+                                        and any(dshape.top_counts)):
+        return (dshape.p - 1) * dshape.ranks[dshape.lc] * nv * bytes_per_el
+    return 0
+
+
+def _shifts(rad: int, p: int) -> int:
+    """Distinct peers of a radius-``rad`` broadcast halo over ``p``."""
+    return min(2 * rad, p - 1)
 
 
 def merged_exchange_bytes(dshape: DistH2Shape, nv: int,

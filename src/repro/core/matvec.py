@@ -35,7 +35,7 @@ def _bgemm(a: jax.Array, b: jax.Array, backend: str) -> jax.Array:
     if backend == "pallas":
         from repro.kernels import ops as kops
         return kops.batched_gemm(a, b)
-    return jnp.einsum("bmk,bkn->bmn", a, b)
+    return jnp.einsum("bmk,bkn->bmn", a, b, precision="highest")
 
 
 def upsweep(shape: H2Shape, data: H2Data, x_leaves: jax.Array,

@@ -41,7 +41,8 @@ def orthogonalize_tree(leaf: jax.Array, transfers: List[jax.Array],
     new_tr: List[jax.Array] = [transfers[0]] + [None] * depth
     for l in range(depth, 0, -1):
         e = transfers[l]                                    # [2**l, k_l, k_{l-1}]
-        re = jnp.einsum("crk,ckp->crp", r[l], e)            # R_c @ E_c
+        re = jnp.einsum("crk,ckp->crp", r[l], e,            # R_c @ E_c
+                        precision="highest")
         nn = e.shape[0]
         kl = re.shape[1]
         klm1 = re.shape[2]
@@ -80,7 +81,8 @@ def _orthogonalize_impl(shape: H2Shape, data: H2Data, backend: str,
                 continue
             rl = jnp.take(ru[l], data.s_rows[l], axis=0)    # [nb, k', k]
             rr = jnp.take(rv[l], data.s_cols[l], axis=0)
-            s_new.append(jnp.einsum("bij,bjk,blk->bil", rl, data.s[l], rr))
+            s_new.append(jnp.einsum("bij,bjk,blk->bil", rl, data.s[l], rr,
+                                    precision="highest"))
     # structure (and therefore the plan) is unchanged; S values are new,
     # so the marshaled buffers are regathered from the plan
     return remarshal(H2Data(

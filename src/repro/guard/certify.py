@@ -93,7 +93,7 @@ def kernel_reference_apply(points: np.ndarray, kernel: Callable,
         for i0 in range(0, n, chunk):
             strip = jnp.asarray(kernel(p[i0:i0 + chunk, None, :],
                                        p[None, :, :]), x.dtype)
-            outs.append(strip @ x)
+            outs.append(jnp.matmul(strip, x, precision="highest"))
         return jnp.concatenate(outs, axis=0)
 
     return apply

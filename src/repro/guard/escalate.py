@@ -48,7 +48,7 @@ def fp64_scalars():
     """Enable-x64 scope for the ``fp64-scalars`` rung: inside it, pass
     ``scalar_dtype=jnp.float64`` to a solver and its reductions accumulate
     in double (the re-trace under x64 is what makes float64 real)."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         yield jnp.float64
 
 

@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import pallas_call
+
 
 def _gemm_kernel(a_ref, b_ref, c_ref):
     k = pl.program_id(3)
@@ -31,21 +33,18 @@ def _gemm_kernel(a_ref, b_ref, c_ref):
 
     a = a_ref[0]          # [bm, bk]
     b = b_ref[0]          # [bk, bn]
-    c_ref[0] += jnp.dot(a, b, preferred_element_type=c_ref.dtype)
+    c_ref[0] += jnp.dot(a, b, preferred_element_type=c_ref.dtype,
+                        precision="highest")
 
 
 def _pick(block: int, dim: int) -> int:
     return dim if dim <= block else block
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk"))
 def batched_gemm(a: jax.Array, b: jax.Array, *, bm: int = 128, bn: int = 128,
-                 bk: int = 128, interpret: bool = True) -> jax.Array:
-    """C[bat] = A[bat] @ B[bat];  A: [B, M, K], B: [B, K, N] -> [B, M, N].
-
-    ``interpret=True`` executes the kernel body in Python on CPU (this
-    container has no TPU); on real hardware pass ``interpret=False``.
-    """
+                 bk: int = 128) -> jax.Array:
+    """C[bat] = A[bat] @ B[bat];  A: [B, M, K], B: [B, K, N] -> [B, M, N]."""
     nb, m, kdim = a.shape
     _, _, n = b.shape
     if 0 in (nb, m, n, kdim):      # zero-size batch/dims (e.g. rank-0 levels)
@@ -59,7 +58,7 @@ def batched_gemm(a: jax.Array, b: jax.Array, *, bm: int = 128, bn: int = 128,
     if kdim % bk:
         bk = kdim
     grid = (nb, m // bm, n // bn, kdim // bk)
-    return pl.pallas_call(
+    return pallas_call(
         _gemm_kernel,
         grid=grid,
         in_specs=[
@@ -68,5 +67,4 @@ def batched_gemm(a: jax.Array, b: jax.Array, *, bm: int = 128, bn: int = 128,
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda b_, i, j, k: (b_, i, j)),
         out_shape=jax.ShapeDtypeStruct((nb, m, n), a.dtype),
-        interpret=interpret,
     )(a, b)

@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import pallas_call
+
 
 def _fused_kernel(blk_ref, col_ref, cnt_ref, s_ref, x_ref, y_ref):
     r = pl.program_id(0)
@@ -37,13 +39,13 @@ def _fused_kernel(blk_ref, col_ref, cnt_ref, s_ref, x_ref, y_ref):
     @pl.when(j < cnt_ref[r])
     def _accumulate():
         y_ref[0] += jnp.dot(s_ref[0], x_ref[0],
-                            preferred_element_type=y_ref.dtype)
+                            preferred_element_type=y_ref.dtype,
+                            precision="highest")
 
 
-@functools.partial(jax.jit, static_argnames=("maxb", "bnv", "interpret"))
+@functools.partial(jax.jit, static_argnames=("maxb", "bnv"))
 def coupling_mv(s: jax.Array, x: jax.Array, blk: jax.Array, col: jax.Array,
-                cnt: jax.Array, *, maxb: int, bnv: int = 128,
-                interpret: bool = True) -> jax.Array:
+                cnt: jax.Array, *, maxb: int, bnv: int = 128) -> jax.Array:
     """-> yhat [rows, k1, nv].
 
     s:   [nb, k1, k2]  blocks in natural (block-list) order
@@ -79,10 +81,9 @@ def coupling_mv(s: jax.Array, x: jax.Array, blk: jax.Array, col: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, k1, bnv), y_map),
     )
-    out = pl.pallas_call(
+    out = pallas_call(
         _fused_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, k1, nv + rem), s.dtype),
-        interpret=interpret,
     )(blk, col, cnt, s, x_p)
     return out[..., :nv] if rem else out

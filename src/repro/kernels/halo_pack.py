@@ -11,21 +11,20 @@ not ``nloc``.  Grid ``(cap,)``; a row is one ``[k, nv]`` tile.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from . import pallas_call
 
 
 def _pack_kernel(idx_ref, x_ref, y_ref):
     y_ref[...] = x_ref[...]
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def halo_pack(x: jax.Array, idx: jax.Array, *,
-              interpret: bool = True) -> jax.Array:
+@jax.jit
+def halo_pack(x: jax.Array, idx: jax.Array) -> jax.Array:
     """-> packed [cap, k, nv].
 
     x:   [n, k, nv]  per-node rows in natural (node) order
@@ -46,9 +45,8 @@ def halo_pack(x: jax.Array, idx: jax.Array, *,
         in_specs=[pl.BlockSpec((1, k, nv), x_map)],
         out_specs=pl.BlockSpec((1, k, nv), y_map),
     )
-    return pl.pallas_call(
+    return pallas_call(
         _pack_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((cap, k, nv), x.dtype),
-        interpret=interpret,
     )(idx, x)

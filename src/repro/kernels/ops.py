@@ -1,29 +1,34 @@
-"""Jit'd public wrappers over the Pallas kernels.
+"""Public entry points of the Pallas kernels, plus the backend dispatch the
+core shares.
 
-``INTERPRET`` is True in this CPU container (Pallas interpret mode executes
-the kernel bodies in Python for correctness validation); on a real TPU set
-``repro.kernels.ops.INTERPRET = False`` (or env REPRO_PALLAS_INTERPRET=0)
-and the same calls compile to Mosaic.
+Each kernel interprets its body where its arrays live on the CPU (the test
+suite runs that way, with ``JAX_PLATFORMS=cpu``) and compiles to Mosaic on
+a TPU; the choice is made per lowering (``repro.kernels.pallas_call``), so
+there is nothing to configure.  ``python chip_smoke.py`` runs the compiled
+kernels on one chip against the ``jnp`` backend.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
 
-from . import batched_gemm as _bg
-from . import batched_qr as _bq
-from . import batched_svd as _bs
-from . import coupling_mv as _cm
-from . import halo_pack as _hp
+from .batched_gemm import batched_gemm
+from .batched_qr import batched_qr
+from .batched_svd import batched_svd
+from .coupling_mv import coupling_mv
+from .halo_pack import halo_pack
 
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
+__all__ = ["backend_qr", "backend_qr_r", "backend_svd", "batched_gemm",
+           "batched_qr", "batched_svd", "coupling_mv", "halo_pack"]
 
 
 def backend_qr(a: jax.Array, backend: str = "jnp", **kw):
     """Backend-dispatched reduced QR (the one helper every caller shares:
-    orthogonalize, compression weights, sketch rangefinder)."""
+    orthogonalize, compression weights, sketch rangefinder).
+
+    Pallas kw: ``panel`` (column-panel width for the WY trailing updates)
+    and ``bb`` (matrices factored per grid step).
+    """
     if backend == "pallas":
         return batched_qr(a, **kw)
     return jnp.linalg.qr(a, mode="reduced")
@@ -37,42 +42,12 @@ def backend_qr_r(a: jax.Array, backend: str = "jnp", **kw) -> jax.Array:
 
 
 def backend_svd(a: jax.Array, backend: str = "jnp", **kw):
+    """Backend-dispatched reduced SVD.
+
+    Pallas kw: ``max_sweeps`` / ``tol`` (off-diagonal-norm early exit: stop
+    when ``||offdiag(A^T A)||_F <= tol * ||A||_F^2``) and ``bb`` (matrices
+    per grid step).
+    """
     if backend == "pallas":
         return batched_svd(a, **kw)
     return jnp.linalg.svd(a, full_matrices=False)
-
-
-def batched_gemm(a: jax.Array, b: jax.Array, **kw) -> jax.Array:
-    return _bg.batched_gemm(a, b, interpret=INTERPRET, **kw)
-
-
-def batched_qr(a: jax.Array, **kw):
-    """Blocked compact-WY Householder QR.
-
-    kw: ``panel`` (column-panel width for the WY trailing updates) and
-    ``bb`` (matrices factored per grid step; defaults to a heuristic that
-    keeps the batch fat when k is small).
-    """
-    return _bq.batched_qr(a, interpret=INTERPRET, **kw)
-
-
-def batched_svd(a: jax.Array, **kw):
-    """Brent-Luk parallel-order one-sided Jacobi SVD.
-
-    kw: ``max_sweeps`` / ``tol`` (off-diagonal-norm early exit: stop when
-    ``||offdiag(A^T A)||_F <= tol * ||A||_F^2``) and ``bb`` (matrices per
-    grid step).
-    """
-    return _bs.batched_svd(a, interpret=INTERPRET, **kw)
-
-
-def coupling_mv(s: jax.Array, x: jax.Array, blk: jax.Array, col: jax.Array,
-                cnt: jax.Array, *, maxb: int, **kw):
-    return _cm.coupling_mv(s, x, blk, col, cnt, maxb=maxb,
-                           interpret=INTERPRET, **kw)
-
-
-def halo_pack(x: jax.Array, idx: jax.Array, **kw) -> jax.Array:
-    """Scalar-prefetch gather of the halo plan's send rows (one packed
-    ppermute payload; see core/halo.py)."""
-    return _hp.halo_pack(x, idx, interpret=INTERPRET, **kw)

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 
 
 def batched_gemm(a: jax.Array, b: jax.Array) -> jax.Array:
-    return jnp.einsum("bmk,bkn->bmn", a, b)
+    return jnp.einsum("bmk,bkn->bmn", a, b, precision="highest")
 
 
 def batched_qr(a: jax.Array):
@@ -37,7 +37,7 @@ def coupling_mv(s: jax.Array, x: jax.Array, blk: jax.Array, col: jax.Array,
     k1 = s.shape[-2]
     sg = jnp.take(s, blk, axis=0, mode="fill", fill_value=0)
     xg = jnp.take(x, col, axis=0)
-    prod = jnp.einsum("bij,bjv->biv", sg, xg)
+    prod = jnp.einsum("bij,bjv->biv", sg, xg, precision="highest")
     mask = (jnp.arange(maxb, dtype=cnt.dtype)[None, :] < cnt[:, None])
     prod = prod.reshape(rows, maxb, k1, -1) * mask[:, :, None, None]
     return prod.sum(axis=1)

@@ -12,6 +12,7 @@ build the production mesh.
 import os
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
                            + os.environ.get("XLA_FLAGS", ""))
+os.environ["JAX_PLATFORMS"] = "cpu"     # a host-platform rehearsal by design
 
 import argparse          # noqa: E402
 import json              # noqa: E402
@@ -24,8 +25,6 @@ import jax               # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np       # noqa: E402
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
-
-from repro.compat import cost_analysis_dict  # noqa: E402
 
 from repro.configs.base import (ALIASES, ARCHS, SHAPES, get_config,  # noqa: E402
                                 shape_applicable)
@@ -192,6 +191,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     lower_s = time.time() - t0
     res: Dict[str, Any] = {
         "arch": arch, "shape": shape_name, "mesh": dict(mesh.shape),
+        "platform": jax.default_backend(),
         "n_devices": n_dev, "kind": shape.kind, "lower_s": round(lower_s, 1),
     }
     if jx_cost is not None:
@@ -206,7 +206,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     compiled = lowered.compile()
     res["compile_s"] = round(time.time() - t0, 1)
 
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     res["xla_flops"] = float(ca.get("flops", -1))       # loop-undercounted
     res["xla_bytes_accessed"] = float(ca.get("bytes accessed", -1))
     try:

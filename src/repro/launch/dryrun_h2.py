@@ -12,6 +12,7 @@ ShapeDtypeStructs; nothing is allocated.
 import os
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
                            + os.environ.get("XLA_FLAGS", ""))
+os.environ["JAX_PLATFORMS"] = "cpu"     # a host-platform rehearsal by design
 
 import argparse          # noqa: E402
 import dataclasses       # noqa: E402
@@ -26,7 +27,6 @@ import numpy as np       # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.core.clustering import build_cluster_tree, regular_grid_points  # noqa: E402
-from repro.compat import cost_analysis_dict, shard_map  # noqa: E402
 from repro.core.admissibility import build_block_structure  # noqa: E402
 from repro.core.dist import (DistH2Data, DistH2Shape, dist_specs,  # noqa: E402
                              dist_h2_matvec_local, dist_compress_local,
@@ -240,8 +240,8 @@ def lower_h2_cell(kind: str, *, dim: int, nv: int, multi_pod: bool,
                 return _kpcg(apply_a, b, tol=1e-6, maxiter=10, axis=axis)
 
             out_sp = result_specs(P(axis))
-            fn = shard_map(step, mesh=mesh, in_specs=(specs, P(axis)),
-                           out_specs=out_sp, check_vma=False)
+            fn = jax.shard_map(step, mesh=mesh, in_specs=(specs, P(axis)),
+                               out_specs=out_sp, check_vma=False)
             out_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), out_sp,
                                   is_leaf=lambda x: isinstance(x, P))
             lowered = jax.jit(fn, in_shardings=(data_sh, x_sh),
@@ -254,7 +254,7 @@ def lower_h2_cell(kind: str, *, dim: int, nv: int, multi_pod: bool,
             def step(d, x):
                 return dist_h2_matvec_local(ds, d, x, axis, comm)
 
-            fn = shard_map(step, mesh=mesh,
+            fn = jax.shard_map(step, mesh=mesh,
                                in_specs=(specs, P(axis, None)),
                                out_specs=P(axis, None), check_vma=False)
             lowered = jax.jit(fn, in_shardings=(data_sh, x_sh),
@@ -267,7 +267,7 @@ def lower_h2_cell(kind: str, *, dim: int, nv: int, multi_pod: bool,
                 return dist_compress_local(ds, d, tgt, axis)
 
             out_specs = dist_specs(dataclasses.replace(ds, ranks=tgt), axis)
-            fn = shard_map(step, mesh=mesh, in_specs=(specs,),
+            fn = jax.shard_map(step, mesh=mesh, in_specs=(specs,),
                                out_specs=out_specs, check_vma=False)
             out_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), out_specs,
                                   is_leaf=lambda x: isinstance(x, P))
@@ -277,6 +277,7 @@ def lower_h2_cell(kind: str, *, dim: int, nv: int, multi_pod: bool,
 
     res = {"cell": f"h2-{dim}d-{kind}" + (f"-nv{nv}" if kind == "matvec"
                                           else ""),
+           "platform": jax.default_backend(),
            "mesh": dict(mesh.shape), "n": ds.n, "depth": depth,
            "k": k, "m": m, "comm": comm,
            "lower_s": round(time.time() - t0, 1),
@@ -291,7 +292,7 @@ def lower_h2_cell(kind: str, *, dim: int, nv: int, multi_pod: bool,
     t0 = time.time()
     compiled = lowered.compile()
     res["compile_s"] = round(time.time() - t0, 1)
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     res["xla_flops"] = float(ca.get("flops", -1))
     hlo = compiled.as_text()
     res["collectives"] = hlo_cost.collective_bytes(hlo)

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.parallel.sharding import Rules
-from repro.compat import shard_map
 from .layers import dense_init
 
 
@@ -125,7 +124,7 @@ def moe_ffn(cfg, p, x: jax.Array, rules: Optional[Rules], mesh: Optional[Mesh]
 
     pspec = {k: (P() if k == "router" else P(rules.tp, None, None))
              for k in p}
-    out = shard_map(
+    out = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(rules.dp, None, None), pspec),
         out_specs=P(rules.dp, None, None),

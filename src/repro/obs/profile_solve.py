@@ -71,13 +71,14 @@ def phase_comm_model(dshape, mg, comm: str, bytes_per_el: int = 4,
     iteration (all_to_all transpositions carrying the stencil halo,
     merged H^2 exchange, deep-halo V-cycle)."""
     from repro.apps.fractional import _fused_default
-    from repro.core.dist import matvec_comm_bytes, merged_exchange_bytes
+    from repro.core.dist import (matvec_comm_bytes, merged_exchange_bytes,
+                                 root_gather_bytes)
     from repro.solvers.mg import mg_halo_bytes
 
     p = dshape.p
     if p <= 1:
         return {ph: 0 for ph in PHASE_ORDER}
-    root = (p - 1) * dshape.ranks[dshape.lc] * bytes_per_el
+    root = root_gather_bytes(dshape, 1, comm, bytes_per_el)
     if _fused_default(fused, comm) and tcaps is not None:
         cap_in, cap_out = tcaps
         exch = merged_exchange_bytes(dshape, 1, comm, bytes_per_el) \
@@ -132,7 +133,6 @@ def build_solve_stages(parts: Dict, mesh, comm: str, loop_m: int = 12):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
     from repro.core.dist import (_coupling_phase, _coupling_phase_overlap,
                                  _dense_phase, _hp_pack_exchange,
                                  _hp_payload_layout, _local_downsweep,
@@ -156,8 +156,8 @@ def build_solve_stages(parts: Dict, mesh, comm: str, loop_m: int = 12):
     hide = solver_hide_flops(mg) if fused else 0
 
     def shmap(fn, in_specs, out_specs):
-        return jax.jit(shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False))
+        return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                     out_specs=out_specs, check_vma=False))
 
     def to_dicts(sweep):
         xh = dict(zip(br_levels, sweep[0]))
@@ -548,6 +548,7 @@ def _worker(args: argparse.Namespace) -> None:
         gap.sort(key=lambda g: -g["delta_us"])
         doc["gap"] = gap
         doc["gap_phases"] = [g["phase"] for g in gap if g["delta_us"] > 0]
+    doc["platform"] = jax.default_backend()
     print(MARKER + json.dumps(doc))
 
 
@@ -559,6 +560,9 @@ def run_profile(argv: Optional[Sequence[str]] = None) -> Dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(root, "src") + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    # fake host devices by design: the child never reaches for a chip
+    # (which its parent may hold)
+    env["JAX_PLATFORMS"] = "cpu"
     cmd = [sys.executable, "-m", "repro.obs.profile_solve", "--worker",
            "--p", str(args.p), "--maxiter", str(args.maxiter),
            "--tol", str(args.tol), "--comms", args.comms]

@@ -129,8 +129,8 @@ def construct_from_matvec(matvec: Callable[[jnp.ndarray], jnp.ndarray],
         key = rng.stream_key(seed, 10_000)
         uv = jax.random.normal(key, (points.shape[0], 2), dtype)
         auv = matvec(uv)
-        a = float(uv[:, 0] @ auv[:, 1])
-        b = float(uv[:, 1] @ auv[:, 0])
+        a = float(jnp.dot(uv[:, 0], auv[:, 1], precision="highest"))
+        b = float(jnp.dot(uv[:, 1], auv[:, 0], precision="highest"))
         if abs(a - b) > 1e-3 * (abs(a) + abs(b) + 1e-30):
             raise ValueError(
                 "construct_from_matvec supports symmetric operators only "
@@ -181,7 +181,7 @@ def construct_from_matvec(matvec: Callable[[jnp.ndarray], jnp.ndarray],
         z = matvec(_node_probe(u_exp[l]))
         av = _gather_block_reads(z, nn, w, kl, sr[l], sc[l])   # [nb, w, k]
         ut = jnp.take(u_exp[l], sr[l], axis=0)
-        s_list.append(jnp.einsum("bwk,bwj->bkj", ut, av))
+        s_list.append(jnp.einsum("bwk,bwj->bkj", ut, av, precision="highest"))
 
     # dense leaves: colored identity probes against the low-rank residual
     shape_lr, data_lr = _assemble(

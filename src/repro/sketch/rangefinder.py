@@ -57,7 +57,7 @@ def orthonormal_basis(b: jax.Array, backend: str = "jnp"
     """
     q, r = _batched_qr(b, backend)
     u, s, _ = _batched_svd(r, backend)
-    return jnp.einsum("nrp,npj->nrj", q, u), s
+    return jnp.einsum("nrp,npj->nrj", q, u, precision="highest"), s
 
 
 @functools.partial(jax.jit, static_argnames=("backend",))
@@ -86,7 +86,7 @@ def pick_rank(svals: jax.Array, thresh: float, cap: int) -> int:
 def _truncate_project(basis: jax.Array, b: jax.Array, rank: int
                       ) -> Tuple[jax.Array, jax.Array]:
     u = basis[..., :rank]
-    return u, jnp.einsum("nwk,nwR->nkR", u, b)
+    return u, jnp.einsum("nwk,nwR->nkR", u, b, precision="highest")
 
 
 def build_nested_bases(sketches: Sequence[Optional[jax.Array]],
@@ -150,7 +150,7 @@ def explicit_bases(u_leaf: jax.Array, e: Sequence[jax.Array]
     exp: List[Optional[jax.Array]] = [None] * (depth + 1)
     exp[depth] = u_leaf
     for l in range(depth, 0, -1):
-        ue = jnp.einsum("cwk,ckp->cwp", exp[l], e[l])
+        ue = jnp.einsum("cwk,ckp->cwp", exp[l], e[l], precision="highest")
         nn, w, kp = ue.shape
         exp[l - 1] = ue.reshape(nn // 2, 2 * w, kp)
     return exp

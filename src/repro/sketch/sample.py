@@ -59,7 +59,8 @@ def apply_kernel_blocks(xt: jax.Array, xs: jax.Array, b: jax.Array,
         xs_c = jax.lax.dynamic_slice_in_dim(xs_p, c * chunk, chunk, axis=1)
         b_c = jax.lax.dynamic_slice_in_dim(b_p, c * chunk, chunk, axis=1)
         kblk = kernel(xt[:, :, None, :], xs_c[:, None, :, :])   # [nb, w, chunk]
-        return acc + jnp.einsum("bwc,bcr->bwr", kblk.astype(b.dtype), b_c)
+        return acc + jnp.einsum("bwc,bcr->bwr", kblk.astype(b.dtype), b_c,
+                                precision="highest")
 
     y0 = jnp.zeros((nb, w, r), b.dtype)
     return jax.lax.fori_loop(0, nchunks, body, y0)
@@ -123,4 +124,4 @@ def project_coupling_blocks(pts_lvl: jax.Array, s_rows: jax.Array,
     vs = jnp.take(v_exp, s_cols, axis=0)                        # [nb, w, k]
     av = apply_kernel_blocks(xt, xs, vs, kernel=kernel, chunk=chunk)
     ut = jnp.take(u_exp, s_rows, axis=0)                        # [nb, w, k]
-    return jnp.einsum("bwk,bwj->bkj", ut, av)
+    return jnp.einsum("bwk,bwj->bkj", ut, av, precision="highest")

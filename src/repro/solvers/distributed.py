@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.dist import (DistH2Data, DistH2Shape, dist_h2_matvec_local,
                              dist_specs, matvec_comm_bytes)
 
@@ -88,16 +87,16 @@ def make_dist_krylov_segment(dshape: DistH2Shape, mesh: Mesh, axis,
         return true / bn_safe, state.res / bn_safe
 
     return {
-        "init": jax.jit(shard_map(init_local, mesh=mesh,
-                                  in_specs=(specs, bspec),
-                                  out_specs=sspecs, check_vma=False)),
-        "segment": jax.jit(shard_map(seg_local, mesh=mesh,
-                                     in_specs=(specs, bspec, sspecs),
-                                     out_specs=sspecs, check_vma=False)),
-        "residual": jax.jit(shard_map(res_local, mesh=mesh,
-                                      in_specs=(specs, bspec, sspecs),
-                                      out_specs=(P(), P()),
-                                      check_vma=False)),
+        "init": jax.jit(jax.shard_map(init_local, mesh=mesh,
+                                      in_specs=(specs, bspec),
+                                      out_specs=sspecs, check_vma=False)),
+        "segment": jax.jit(jax.shard_map(seg_local, mesh=mesh,
+                                         in_specs=(specs, bspec, sspecs),
+                                         out_specs=sspecs, check_vma=False)),
+        "residual": jax.jit(jax.shard_map(res_local, mesh=mesh,
+                                          in_specs=(specs, bspec, sspecs),
+                                          out_specs=(P(), P()),
+                                          check_vma=False)),
         "state_specs": sspecs,
     }
 
@@ -139,8 +138,8 @@ def make_dist_krylov(dshape: DistH2Shape, mesh: Mesh, axis,
         return gmres(apply_a, b, m=restart, tol=tol, maxiter=maxiter,
                      axis=axis)
 
-    shmapped = shard_map(local, mesh=mesh, in_specs=(specs, bspec),
-                         out_specs=result_specs(bspec), check_vma=False)
+    shmapped = jax.shard_map(local, mesh=mesh, in_specs=(specs, bspec),
+                             out_specs=result_specs(bspec), check_vma=False)
     return jax.jit(shmapped)
 
 

@@ -109,7 +109,7 @@ def _dot(u: jax.Array, v: jax.Array, axis=None, dt=None) -> jax.Array:
     """Global <u, v> over all elements; psum over ``axis`` when sharded.
 
     ``dt`` (the fp64 escalation hook): accumulate the products in that
-    dtype — meaningful under ``jax.experimental.enable_x64``; without x64
+    dtype — meaningful under ``jax.enable_x64(True)``; without x64
     it canonicalizes back to f32 and is a no-op.
     """
     if dt is not None:
@@ -498,9 +498,9 @@ def _arnoldi(op: Callable, v0: jax.Array, m: int, axis=None):
             w = op(V[j])
         mask = (jnp.arange(m + 1) <= j).astype(w.dtype)
         h1 = vdot_all(V, w) * mask
-        w = w - jnp.tensordot(h1, V, axes=1)
+        w = w - jnp.tensordot(h1, V, axes=1, precision="highest")
         h2 = vdot_all(V, w) * mask                 # CGS second pass
-        w = w - jnp.tensordot(h2, V, axes=1)
+        w = w - jnp.tensordot(h2, V, axes=1, precision="highest")
         h = h1 + h2
         hn = _norm(w, axis)
         v_next = jnp.where(hn > 0, w / jnp.where(hn > 0, hn, 1.0), 0.0)
@@ -573,11 +573,11 @@ def gmres(apply_a: Callable, b: jax.Array,
         # min_y ||beta e1 - H y||: ridge-regularized normal equations keep
         # the solve well-posed through happy breakdown (zero H columns)
         e1 = jnp.zeros((m + 1,), b.dtype).at[0].set(beta)
-        g = H.T @ H
+        g = jnp.matmul(H.T, H, precision="highest")
         ridge = 1e-7 * (jnp.trace(g) / m + 1e-30)
         y = jnp.linalg.solve(g + ridge * jnp.eye(m, dtype=b.dtype),
-                             H.T @ e1)
-        x_new = x + jnp.tensordot(y, V[:m], axes=1)
+                             jnp.matmul(H.T, e1, precision="highest"))
+        x_new = x + jnp.tensordot(y, V[:m], axes=1, precision="highest")
         r_new = b - apply_a(x_new)
         res_new = _norm(r_new, axis)
         # accept only improving restarts: at the dtype's stagnation floor

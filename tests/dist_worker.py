@@ -405,7 +405,6 @@ def mg_gathered_check(rng):
     to strip-shard, n_sharded == 0): the strips are all_gather'ed, the
     whole V-cycle runs replicated, and the own strip is sliced back —
     must equal the p=1 preconditioner exactly."""
-    from repro.compat import shard_map
     from repro.solvers.mg import (build_grid_mg, mg_halo_bytes,
                                   mg_precond_local, mg_specs)
 
@@ -420,7 +419,7 @@ def mg_gathered_check(rng):
     ref = np.asarray(mg_precond_local(mg1, a1, r))
 
     mesh_p = jax.make_mesh((p,), ("blk",))
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda aa, rr: mg_precond_local(mg8, aa, rr, "blk"),
         mesh=mesh_p, in_specs=(mg_specs(mg8, "blk"), P("blk")),
         out_specs=P("blk"), check_vma=False)

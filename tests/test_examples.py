@@ -56,3 +56,31 @@ class TestServeSolver:
         # the stream hit the operator (and compiled solver) in the cache
         assert rb.metrics["cache"]["hits"] >= 1
         assert rb.metrics["cache"]["misses"] == 2
+
+
+class TestCompileCache:
+    """Where the entry points keep JAX's persistent compilation cache."""
+
+    @pytest.fixture(autouse=True)
+    def _restore_cache_dir(self):
+        import jax
+        was = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", was)
+
+    def test_environment_variable_stands(self, tmp_path, monkeypatch):
+        import jax
+        from repro.launch.compile_cache import enable_compile_cache
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "c"))
+        before = jax.config.jax_compilation_cache_dir
+        assert enable_compile_cache(tmp_path) == str(tmp_path / "c")
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_fixed_path_in_the_checkout(self, tmp_path, monkeypatch):
+        import jax
+        from repro.launch.compile_cache import enable_compile_cache
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(tmp_path.resolve() / ".jax_cache")
+        assert enable_compile_cache(tmp_path) == want
+        assert enable_compile_cache(tmp_path) == want     # same every run
+        assert jax.config.jax_compilation_cache_dir == want

@@ -6,7 +6,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import get_config
-from repro.compat import shard_map
 
 
 class TestH2Mixer:
@@ -147,7 +146,7 @@ ENTRY %main (p: f32[4]) -> f32[4] {
         def f(x):
             def inner(xx):
                 return xx @ xx
-            return shard_map(inner, mesh=mesh,
+            return jax.shard_map(inner, mesh=mesh,
                                  in_specs=jax.sharding.PartitionSpec(),
                                  out_specs=jax.sharding.PartitionSpec(),
                                  check_vma=False)(x)
