@@ -62,34 +62,19 @@ def check(ok: bool, what: str) -> None:
         raise SmokeFailure(what)
 
 
-class CompileStats:
-    """Backend compile seconds and persistent-cache hits, from JAX's own
-    monitoring events."""
+def compile_snapshot():
+    """Compile seconds (the obs registry's ``compile/*`` spans: trace,
+    lowering, backend compile) and persistent-cache hits and misses."""
+    from repro.obs import REGISTRY, counter
+    secs = sum(ns for name, (_, ns) in REGISTRY.totals().items()
+               if name.startswith("compile/")) * 1e-9
+    return secs, counter("compile/cache-hits"), counter("compile/cache-misses")
 
-    def __init__(self, jax):
-        self.compile_s = 0.0
-        self.hits = 0
-        self.misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
 
-    def _duration(self, event, secs, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compile_s += secs
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def snapshot(self):
-        return self.compile_s, self.hits, self.misses
-
-    def since(self, snap):
-        return {"compile_s": self.compile_s - snap[0],
-                "cache_hits": self.hits - snap[1],
-                "cache_misses": self.misses - snap[2]}
+def compiled_since(snap):
+    now = compile_snapshot()
+    return {"compile_s": now[0] - snap[0], "cache_hits": now[1] - snap[1],
+            "cache_misses": now[2] - snap[2]}
 
 
 def report(phase: str, **fields) -> None:
@@ -122,35 +107,34 @@ def main(argv=None) -> int:
     sys.path.insert(0, str(ROOT / "src"))
     from repro.launch.compile_cache import enable_compile_cache
     cache_dir = enable_compile_cache(ROOT)
-    stats = CompileStats(jax)
     d0 = devices[0]
     report("device", platform=d0.platform, kind=d0.device_kind,
            count=len(devices), cache_dir=cache_dir)
 
-    prob, fp = build(jax, stats)
-    res = solve_one_device(jax, stats, prob)
+    prob, fp = build(jax)
+    res = solve_one_device(jax, prob)
     if args.chips == 4:
-        solve_distributed(jax, stats, prob, res, devices[:4])
+        solve_distributed(jax, prob, res, devices[:4])
     else:
         certify(jax, prob, fp)
-        pallas(jax, stats, prob, fp)
-    report("cache", **stats.since((0.0, 0, 0)))
+        pallas(jax, prob, fp)
+    report("cache", **compiled_since((0.0, 0, 0)))
     print(json.dumps({"ok": True, "device": {
         "platform": d0.platform, "kind": d0.device_kind,
         "count": len(devices)}}))
     return 0
 
 
-def build(jax, stats):
+def build(jax):
     from repro.apps.fractional import FractionalProblem
 
     fp = FractionalProblem(N)
-    snap = stats.snapshot()
+    snap = compile_snapshot()
     t0 = time.perf_counter()
     prob = fp.build()
     jax.block_until_ready((prob["data"], prob["d_diag"]))
     setup_s = time.perf_counter() - t0
-    report("build", n=N * N, setup_s=setup_s, **stats.since(snap),
+    report("build", n=N * N, setup_s=setup_s, **compiled_since(snap),
            ranks=list(prob["shape"].ranks),
            peak_bytes_in_use=peak_bytes(jax.devices()[:1]))
     return prob, fp
@@ -179,7 +163,7 @@ def certify(jax, prob, fp):
                    f"{cert.rel_err} > {CERT_TOL}")
 
 
-def solve_one_device(jax, stats, prob):
+def solve_one_device(jax, prob):
     """``solve``'s body on the built problem: jitted PCG + GMG V-cycles."""
     import jax.numpy as jnp
     from repro.apps.fractional import make_operator, make_preconditioner
@@ -189,12 +173,12 @@ def solve_one_device(jax, stats, prob):
     apply_a = make_operator(prob)
     pre = make_preconditioner(prob)
     b = jnp.ones((N * N,), jnp.float32) * prob["h"] ** 2
-    snap = stats.snapshot()
+    snap = compile_snapshot()
     t0 = time.perf_counter()
     solver = jax.jit(lambda rhs: pcg(apply_a, rhs, pre, tol=SOLVE_TOL,
                                      maxiter=MAXITER)).lower(b).compile()
     compile_s = time.perf_counter() - t0
-    cstats = stats.since(snap)
+    cstats = compiled_since(snap)
     t0 = time.perf_counter()
     res = solver(b)
     jax.block_until_ready(res.x)
@@ -216,7 +200,7 @@ def solve_one_device(jax, stats, prob):
     return res
 
 
-def pallas(jax, stats, prob, fp):
+def pallas(jax, prob, fp):
     """Compiled Pallas kernels against the jnp backend."""
     import jax.numpy as jnp
     from repro.core.compression import (_leaf_factors_jit,
@@ -251,7 +235,7 @@ def pallas(jax, stats, prob, fp):
     text = _leaf_factors_jit.lower(ru[shape0.depth],
                                    "pallas").compile().as_text()
     check("tpu_custom_call" in text, "pallas SVD stage holds no kernel")
-    snap = stats.snapshot()
+    snap = compile_snapshot()
     t0 = time.perf_counter()
     shape_p, data_p = compress(shape0, data0, tol=fp.h2_tol,
                                backend="pallas")
@@ -264,7 +248,7 @@ def pallas(jax, stats, prob, fp):
                           tol=COMPRESS_TOL)
     report("pallas_compress", ranks=list(shape_p.ranks),
            jnp_ranks=list(shape.ranks), rel_err=cert.rel_err,
-           rel_diff_vs_jnp=same.rel_err, s=compress_s, **stats.since(snap))
+           rel_diff_vs_jnp=same.rel_err, s=compress_s, **compiled_since(snap))
     # h2_tol = 1e-6 cuts at sigma > 1e-6 sigma_max, ~8 f32 ulps of
     # sigma_max: XLA's SVD and the Jacobi kernel put a sigma that close to
     # the cut on either side of it, so a level's rank may differ by one
@@ -275,7 +259,7 @@ def pallas(jax, stats, prob, fp):
                    f"{cert.rel_err} > {CERT_TOL}")
 
 
-def solve_distributed(jax, stats, prob, res1, devices):
+def solve_distributed(jax, prob, res1, devices):
     """``make_dist_solve`` on a 4-device mesh against the one-device
     solve of the same problem."""
     import jax.numpy as jnp
@@ -289,11 +273,11 @@ def solve_distributed(jax, stats, prob, res1, devices):
     args = parts["place"](parts["args"])
     b = jnp.ones((N * N,), jnp.float32) * prob["h"] ** 2
     b_dev = jax.device_put(b, NamedSharding(mesh, P("blk")))
-    snap = stats.snapshot()
+    snap = compile_snapshot()
     t0 = time.perf_counter()
     fn = parts["fn"].lower(*args, b_dev).compile()
     compile_s = time.perf_counter() - t0
-    cstats = stats.since(snap)
+    cstats = compiled_since(snap)
     t0 = time.perf_counter()
     res = fn(*args, b_dev)
     jax.block_until_ready(res.x)

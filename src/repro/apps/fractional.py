@@ -47,14 +47,13 @@ from repro.core.matvec import h2_matvec
 from repro.core.repartition import repartition_h2
 from repro.core.structure import H2Data, H2Shape
 from repro.checkpoint.manager import CheckpointManager
-from repro.guard.escalate import GUARD_COUNTERS, fp64_scalars, \
-    run_with_guards
+from repro.guard.escalate import fp64_scalars, run_with_guards
 from repro.guard.status import worst_status
-from repro.obs.trace import phase
+from repro.obs.trace import count, phase, span
 from repro.runtime.chaos import ChaosPlan, ChaosReport, FaultEvent
 from repro.runtime.fault import (StepFailure, StragglerMonitor,
                                  run_with_restarts)
-from repro.solvers import (TRACE_COUNTS, build_grid_mg, mg_halo_bytes,
+from repro.solvers import (build_grid_mg, mg_halo_bytes,
                            mg_precond_local, mg_specs, pcg_init, pcg_segment,
                            pcg_state_specs, result_specs, solver_hide_flops)
 from repro.solvers import gmres as _gmres
@@ -109,6 +108,10 @@ class FractionalProblem:
             eta=self.eta), True
 
     def build(self, compress_k: bool = True) -> Dict:
+        """K, D, kappa and the grid<->tree maps.  Host spans:
+        ``build/compress-k`` (K's recompression) and ``build/d-assembly``
+        (everything that exists only to get D, K-hat's ``construct/*``
+        spans inside it)."""
         n = self.n
         h = 2.0 / n
         pts = interior_grid(n)
@@ -117,27 +120,13 @@ class FractionalProblem:
             pts, fractional_kernel_2d(self.beta),
             fractional_kernel_2d(self.beta, xp=jnp), m)
         if compress_k and needs_compress:
-            shape, data = compress(shape, data, tol=self.h2_tol)
+            with span("build/compress-k"):
+                shape, data = compress(shape, data, tol=self.h2_tol)
+                jax.block_until_ready(data)     # K's device work in here
 
         # --- D via Khat @ 1 on the extended grid (Eq. 10) ---
-        pts_ext, inside = extended_grid(n)
-        m_ext = 36 if (9 * n * n) % 36 == 0 else 16
-        n_ext = pts_ext.shape[0]
-        while n_ext % m_ext or ((n_ext // m_ext) & (n_ext // m_ext - 1)):
-            m_ext *= 2
-            if m_ext > n_ext:
-                m_ext = n_ext
-                break
-        (eshape, edata, etree, _), _ = self._construct(
-            pts_ext, fractional_kernel_2d_positive(self.beta),
-            fractional_kernel_2d_positive(self.beta, xp=jnp), m_ext)
-        ones = jnp.ones((eshape.n, 1), jnp.float32)
-        row_sums = np.asarray(h2_matvec(eshape, edata, ones))[:, 0]
-        # undo the tree permutation, restrict to Omega
-        unperm = np.empty(eshape.n, np.int64)
-        unperm[etree.perm] = np.arange(eshape.n)
-        d_ext = row_sums[unperm]
-        d_diag = d_ext[inside]                      # grid-ordered, Omega only
+        with span("build/d-assembly"):
+            d_diag = self._assemble_d()
 
         # --- C: kappa-weighted 5-point Laplacian, gamma = h^(-2 beta) ---
         kappa = diffusivity_2d(pts).reshape(n, n)
@@ -154,6 +143,27 @@ class FractionalProblem:
             "kappa": jnp.asarray(kappa, jnp.float32),
             "gamma": gamma, "h": h, "n": n,
         }
+
+    def _assemble_d(self) -> np.ndarray:
+        """D_ii = (Khat @ 1)_i restricted to Omega (Eq. 10), grid order."""
+        n = self.n
+        pts_ext, inside = extended_grid(n)
+        m_ext = 36 if (9 * n * n) % 36 == 0 else 16
+        n_ext = pts_ext.shape[0]
+        while n_ext % m_ext or ((n_ext // m_ext) & (n_ext // m_ext - 1)):
+            m_ext *= 2
+            if m_ext > n_ext:
+                m_ext = n_ext
+                break
+        (eshape, edata, etree, _), _ = self._construct(
+            pts_ext, fractional_kernel_2d_positive(self.beta),
+            fractional_kernel_2d_positive(self.beta, xp=jnp), m_ext)
+        ones = jnp.ones((eshape.n, 1), jnp.float32)
+        row_sums = np.asarray(h2_matvec(eshape, edata, ones))[:, 0]
+        # undo the tree permutation, restrict to Omega
+        unperm = np.empty(eshape.n, np.int64)
+        unperm[etree.perm] = np.arange(eshape.n)
+        return row_sums[unperm][inside]
 
 
 def apply_c(u: jax.Array, kappa: jax.Array, h: float) -> jax.Array:
@@ -183,9 +193,15 @@ def make_operator(prob: Dict) -> Callable[[jax.Array], jax.Array]:
     unperm_j = jnp.asarray(unperm)
 
     def apply_a(u: jax.Array) -> jax.Array:
-        ku = h2_matvec(shape, data, u[perm_j][:, None])[:, 0][unperm_j]
-        cu = apply_c(u.reshape(n, n), kappa, h).ravel()
-        return (h * h) * (d_diag * u + ku + gamma * cu)
+        # the phases _dist_apply_a gives the same work
+        with phase("solve/transpose-in"):
+            ut = u[perm_j][:, None]
+        kut = h2_matvec(shape, data, ut)
+        with phase("solve/transpose-out"):
+            ku = kut[:, 0][unperm_j]
+        with phase("solve/stencil"):
+            cu = apply_c(u.reshape(n, n), kappa, h).ravel()
+            return (h * h) * (d_diag * u + ku + gamma * cu)
 
     return apply_a
 
@@ -449,7 +465,7 @@ def make_dist_solve(prob: Dict, mesh: Mesh, axis="blk",
     bf16 = comm.endswith("-bf16")
 
     def local(d, aux, mga, b):
-        TRACE_COUNTS["dist_fractional"] += 1
+        count("retrace/dist_fractional")
 
         def apply_a(x):
             return _dist_apply_a(dshape, d, aux, mg, mga, x, axis, comm,
@@ -753,7 +769,7 @@ def solve_distributed_elastic(n: int, mesh: Mesh, axis="blk",
             # a bf16-payload exchange drops to full fp32 payloads before
             # resuming from the checkpoint
             ctx["comm"] = ctx["comm"][:-len("-bf16")]
-            GUARD_COUNTERS["elastic/fp32-comm"] += 1
+            count("guard/elastic/fp32-comm")
             build_ctx(ctx["mesh"])
             escalated = True
         if mgr is not None:

@@ -38,7 +38,6 @@ per branch inside ``shard_map``.
 """
 from __future__ import annotations
 
-import collections
 import dataclasses
 import functools
 from typing import List, Optional, Sequence, Tuple
@@ -46,14 +45,10 @@ from typing import List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.obs.trace import phase
+from repro.obs.trace import count, phase, span
 
 from .structure import H2Data, H2Shape, remarshal, shape_of, \
     stack_blocks_by_plan
-
-# incremented when the fused fixed-rank pipeline is (re)traced — the
-# single-dispatch regression test asserts repeat calls do not retrace
-TRACE_COUNTS = collections.Counter()
 
 
 def _batched_qr_r(a: jax.Array, backend: str) -> jax.Array:
@@ -207,12 +202,13 @@ def _pack_truncated(shape: H2Shape, data: H2Data, u_leaf, v_leaf, e_new,
                         symmetric=shape.symmetric,
                         row_maxb=shape.row_maxb, col_maxb=shape.col_maxb,
                         dense_maxb=shape.dense_maxb)
-    new_data = remarshal(H2Data(
-        u_leaf=u_leaf, v_leaf=v_leaf, e=e_new, f=f_new,
-        s=s_new, s_rows=list(data.s_rows),
-        s_cols=list(data.s_cols), dense=data.dense,
-        d_rows=data.d_rows, d_cols=data.d_cols,
-        plan=data.plan, dense_mar=data.dense_mar), dense=False)
+    with phase("compress/project-s"):     # S's marshaled copy
+        new_data = remarshal(H2Data(
+            u_leaf=u_leaf, v_leaf=v_leaf, e=e_new, f=f_new,
+            s=s_new, s_rows=list(data.s_rows),
+            s_cols=list(data.s_cols), dense=data.dense,
+            d_rows=data.d_rows, d_cols=data.d_cols,
+            plan=data.plan, dense_mar=data.dense_mar), dense=False)
     return new_shape, new_data
 
 
@@ -257,25 +253,35 @@ def truncate(shape: H2Shape, data: H2Data, ru: List[jax.Array],
 
 
 # jitted single-sweep steps (cached per level shape; the tol path stays
-# host-in-the-loop only for the integer rank picks)
-_leaf_factors_jit = jax.jit(truncation_leaf_factors,
-                            static_argnames=("backend",))
-_inner_factors_jit = jax.jit(truncation_inner_factors,
-                             static_argnames=("backend",))
+# host-in-the-loop only for the integer rank picks), each under the scope
+# the fixed-rank ``truncate`` puts its sweep in
+@functools.partial(jax.jit, static_argnames=("backend",))
+def _leaf_factors_jit(r_leaf: jax.Array, backend: str = "jnp"):
+    with phase("compress/truncate"):
+        return truncation_leaf_factors(r_leaf, backend)
+
+
+@functools.partial(jax.jit, static_argnames=("backend",))
+def _inner_factors_jit(p: jax.Array, transfer: jax.Array,
+                       r_parent: jax.Array, backend: str = "jnp"):
+    with phase("compress/truncate"):
+        return truncation_inner_factors(p, transfer, r_parent, backend)
 
 
 @functools.partial(jax.jit, static_argnames=("rq",))
 def _leaf_apply_jit(leaf: jax.Array, w: jax.Array, rq: int):
-    wk = w[..., :rq]
-    new_leaf = jnp.einsum("nmk,nkr->nmr", leaf, wk, precision="highest")
-    return new_leaf, jnp.swapaxes(wk, -1, -2)
+    with phase("compress/truncate"):
+        wk = w[..., :rq]
+        new_leaf = jnp.einsum("nmk,nkr->nmr", leaf, wk, precision="highest")
+        return new_leaf, jnp.swapaxes(wk, -1, -2)
 
 
 @functools.partial(jax.jit, static_argnames=("rp", "nn"))
 def _inner_apply_jit(g: jax.Array, stack: jax.Array, rp: int, nn: int):
-    gk = g[..., :rp]
-    return gk.reshape(nn, stack.shape[1] // 2, rp), \
-        truncation_project(gk, stack)
+    with phase("compress/truncate"):
+        gk = g[..., :rp]
+        return gk.reshape(nn, stack.shape[1] // 2, rp), \
+            truncation_project(gk, stack)
 
 
 @functools.partial(jax.jit, static_argnames=("shape",))
@@ -295,19 +301,27 @@ def truncate_by_tol(shape: H2Shape, data: H2Data, ru: List[jax.Array],
     over both trees, the same pick the two-sweep reference makes), then the
     already-computed factors are sliced to that rank and the sweep
     continues — no second factorization pass.
+
+    Host syncs: ``depth + 2`` per call (the scale, the leaf rank, one rank
+    per inner level), each in a host span ``compress/rank-pick`` and
+    counted as ``compress/host-syncs``.
     """
     depth = shape.depth
 
     wu, su = _leaf_factors_jit(ru[depth], backend)
     sym = shape.symmetric and data.v_leaf is data.u_leaf
     wv, sv = (wu, su) if sym else _leaf_factors_jit(rv[depth], backend)
-    scale = float(jnp.maximum(su.max(), sv.max()))
+    with span("compress/rank-pick"):
+        count("compress/host-syncs")
+        scale = float(jnp.maximum(su.max(), sv.max()))
     thresh = tol * scale
 
     def count2(s_a, s_b) -> int:
-        c = jnp.maximum((s_a > thresh).sum(axis=-1).max(),
-                        (s_b > thresh).sum(axis=-1).max())
-        return int(jnp.maximum(c, 1))
+        with span("compress/rank-pick"):
+            count("compress/host-syncs")
+            c = jnp.maximum((s_a > thresh).sum(axis=-1).max(),
+                            (s_b > thresh).sum(axis=-1).max())
+            return int(jnp.maximum(c, 1))
 
     rq = min(count2(su, sv), shape.ranks[depth])
 
@@ -434,7 +448,7 @@ def _orthogonalized(shape: H2Shape, data: H2Data, backend: str,
 def _orthogonalize_weights(shape: H2Shape, data: H2Data, backend: str,
                            aliased: bool):
     """Stage A of the fused tol path: orthogonalize + weights, one program."""
-    TRACE_COUNTS["orthogonalize_weights"] += 1
+    count("retrace/orthogonalize_weights")
     shape, data = _orthogonalized(shape, data, backend, aliased)
     ru, rv = compression_weights(shape, data, backend, aliased=aliased)
     return data, ru, rv
@@ -452,7 +466,7 @@ def _compress_fixed(shape: H2Shape, data: H2Data,
     trace into a single jaxpr — one dispatch from Python per (structure,
     target_ranks) pair, no host round-trips in between.
     """
-    TRACE_COUNTS["compress_fixed"] += 1
+    count("retrace/compress_fixed")
     if not assume_orthogonal:
         shape, data = _orthogonalized(shape, data, backend, aliased)
     elif aliased:
@@ -478,6 +492,7 @@ def compress(shape: H2Shape, data: H2Data, tol: Optional[float] = None,
     aliasing) — it is the reference of the rank-pick property test and the
     baseline of the compression benchmark.
     """
+    count("compress/calls")
     aliased = bool(shape.symmetric and data.v_leaf is data.u_leaf)
     if target_ranks is not None:
         new_data = _compress_fixed(shape, data, tuple(int(t) for t in

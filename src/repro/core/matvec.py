@@ -150,7 +150,10 @@ def h2_matvec(shape: H2Shape, data: H2Data, x: jax.Array,
               backend: str = "jnp") -> jax.Array:
     """y = A x with A = A_de + <U,S,V^T>;  x: [N, nv] in tree order."""
     nv = x.shape[-1]
-    x_leaves = x.reshape(shape.n_leaves, shape.leaf_size, nv)
+    # the layout changes around the tree phases, deliberately not under
+    # hgemv/ (whose scopes time the H^2 work proper)
+    with phase("matvec/layout"):
+        x_leaves = x.reshape(shape.n_leaves, shape.leaf_size, nv)
     with phase("hgemv/upsweep"):
         xhat = upsweep(shape, data, x_leaves, backend)
     with phase("hgemv/coupling-gemm"):
@@ -159,7 +162,8 @@ def h2_matvec(shape: H2Shape, data: H2Data, x: jax.Array,
         y_lr = downsweep(shape, data, yhat, backend)
     with phase("hgemv/dense"):
         y_de = dense_multiply(shape, data, x_leaves, backend)
-    return (y_lr + y_de).reshape(shape.n, nv)
+    with phase("matvec/layout"):
+        return (y_lr + y_de).reshape(shape.n, nv)
 
 
 def h2_matvec_flops(shape: H2Shape, nv: int) -> int:

@@ -83,13 +83,13 @@ def _orthogonalize_impl(shape: H2Shape, data: H2Data, backend: str,
             rr = jnp.take(rv[l], data.s_cols[l], axis=0)
             s_new.append(jnp.einsum("bij,bjk,blk->bil", rl, data.s[l], rr,
                                     precision="highest"))
-    # structure (and therefore the plan) is unchanged; S values are new,
-    # so the marshaled buffers are regathered from the plan
-    return remarshal(H2Data(
-        u_leaf=u_leaf, v_leaf=v_leaf, e=e_new, f=f_new, s=s_new,
-        s_rows=list(data.s_rows), s_cols=list(data.s_cols),
-        dense=data.dense, d_rows=data.d_rows, d_cols=data.d_cols,
-        plan=data.plan, dense_mar=data.dense_mar), dense=False)
+        # structure (and therefore the plan) is unchanged; S values are
+        # new, so the marshaled buffers are regathered from the plan
+        return remarshal(H2Data(
+            u_leaf=u_leaf, v_leaf=v_leaf, e=e_new, f=f_new, s=s_new,
+            s_rows=list(data.s_rows), s_cols=list(data.s_cols),
+            dense=data.dense, d_rows=data.d_rows, d_cols=data.d_cols,
+            plan=data.plan, dense_mar=data.dense_mar), dense=False)
 
 
 @functools.partial(jax.jit, static_argnames=("shape", "backend", "aliased"))

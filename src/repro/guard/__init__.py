@@ -15,7 +15,7 @@ Three pillars, one subsystem:
 - **Escalation policies** (``escalate``): ``run_with_guards`` maps a
   failed/suspect solve onto a recovery ladder (fp64 scalar accumulation,
   fp32 halo payloads, oversampling escalation, looser tolerance), with
-  every trip counted in ``GUARD_COUNTERS``.
+  every trip counted in the obs counters ``guard/...``.
 
 Deterministic numerical-fault drills live in ``drills`` and are exercised
 by the chaos harness and ``tests/test_guard.py``.
@@ -28,7 +28,7 @@ from .validate import ValidationReport, check_orthogonal, validate_dist_h2, \
     validate_h2
 from .certify import (CERT_STREAM, Certificate, certify_h2, certify_matvec,
                       kernel_reference_apply, probe_block)
-from .escalate import (GUARD_COUNTERS, GuardOutcome, construct_h2_certified,
+from .escalate import (GuardOutcome, construct_h2_certified,
                        default_accept, fp64_scalars, reset_guard_counters,
                        run_with_guards)
 from .drills import drill_corrupt_operator, drill_near_singular, \
@@ -42,7 +42,7 @@ __all__ = [
     "check_orthogonal",
     "Certificate", "certify_matvec", "certify_h2",
     "kernel_reference_apply", "probe_block", "CERT_STREAM",
-    "GUARD_COUNTERS", "GuardOutcome", "run_with_guards", "default_accept",
+    "GuardOutcome", "run_with_guards", "default_accept",
     "fp64_scalars", "construct_h2_certified", "reset_guard_counters",
     "drill_corrupt_operator", "drill_rank_starved", "drill_near_singular",
 ]

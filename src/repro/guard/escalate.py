@@ -3,8 +3,9 @@
 ``run_with_guards`` is the generic orchestrator: it walks a ladder of
 named *rungs* (thunks producing a solve-like result), accepts the first
 result that passes (converged, every status OK), and counts every
-attempt / acceptance / rejection in ``GUARD_COUNTERS`` so the obs layer
-and the serving metrics can surface trip rates.  The rung vocabulary the
+attempt / acceptance / rejection as an obs counter ``guard/...``
+(``repro.obs.count``) so the obs layer and the serving metrics can
+surface trip rates.  The rung vocabulary the
 apps wire in (DESIGN.md §11):
 
 - ``fp64-scalars`` — re-trace the solve under :func:`fp64_scalars` with
@@ -19,12 +20,11 @@ apps wire in (DESIGN.md §11):
 - ``loose`` — a looser-tolerance solve as the last resort (serving keeps
   a looser-tol cached operator for the same purpose).
 
-Counters are process-global and monotone, like ``solvers.TRACE_COUNTS``;
+Counters are process-global and monotone, like the ``retrace/...`` ones;
 ``reset_guard_counters`` is for tests.
 """
 from __future__ import annotations
 
-import collections
 import contextlib
 import dataclasses
 from typing import Any, Callable, List, Optional, Sequence, Tuple
@@ -33,14 +33,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.trace import REGISTRY, count
+
 from .certify import Certificate, certify_h2, kernel_reference_apply
 from .status import STATUS_OK, status_name, worst_status
 
-GUARD_COUNTERS: collections.Counter = collections.Counter()
-
 
 def reset_guard_counters() -> None:
-    GUARD_COUNTERS.clear()
+    """Zero the ``guard/...`` counters (tests)."""
+    REGISTRY.clear("guard/")
 
 
 @contextlib.contextmanager
@@ -95,13 +96,13 @@ def run_with_guards(rungs: Sequence[Tuple[str, Callable[[], Any]]],
     last_name = ""
     last_exc: Optional[BaseException] = None
     for i, (name, thunk) in enumerate(rungs):
-        GUARD_COUNTERS[f"attempt/{name}"] += 1
+        count(f"guard/attempt/{name}")
         if i > 0:
-            GUARD_COUNTERS["escalations"] += 1
+            count("guard/escalations")
         try:
             result = thunk()
         except Exception as e:            # noqa: BLE001 — rung failure is data
-            GUARD_COUNTERS[f"raise/{name}"] += 1
+            count(f"guard/raise/{name}")
             attempts.append((name, f"raised:{type(e).__name__}"))
             last_exc, last, last_name = e, None, name
             continue
@@ -109,13 +110,13 @@ def run_with_guards(rungs: Sequence[Tuple[str, Callable[[], Any]]],
         verdict = status_name(getattr(result, "status", None))
         attempts.append((name, verdict))
         if verdict != "ok":
-            GUARD_COUNTERS[f"status/{verdict}"] += 1
+            count(f"guard/status/{verdict}")
         if accept(result):
-            GUARD_COUNTERS[f"accept/{name}"] += 1
+            count(f"guard/accept/{name}")
             return GuardOutcome(result=result, rung=name, attempts=attempts,
                                 ok=True)
-        GUARD_COUNTERS[f"reject/{name}"] += 1
-    GUARD_COUNTERS["exhausted"] += 1
+        count(f"guard/reject/{name}")
+    count("guard/exhausted")
     if last is None and last_exc is not None:
         raise last_exc
     return GuardOutcome(result=last, rung=last_name, attempts=attempts,
@@ -134,7 +135,7 @@ def construct_h2_certified(points: np.ndarray, kernel: Callable,
 
     Returns ``(shape, data, tree, bs, cert, rounds)``; the last round's
     result is returned even when it fails certification (``cert.ok``
-    tells).  Every escalation round is counted in ``GUARD_COUNTERS``.
+    tells).  Every escalation round is counted (``guard/construct/...``).
     """
     from repro.core.construction import construct_h2
 
@@ -153,9 +154,9 @@ def construct_h2_certified(points: np.ndarray, kernel: Callable,
                           seed=int(opts.get("seed", 0)), tol=cert_tol)
         if cert.ok:
             if rnd > 0:
-                GUARD_COUNTERS["construct/recovered"] += 1
+                count("guard/construct/recovered")
             return (*out, cert, rnd + 1)
-        GUARD_COUNTERS["construct/cert-failed"] += 1
+        count("guard/construct/cert-failed")
         # double the rangefinder budget: more oversampling columns, more
         # initial samples, a higher rank cap (a starved cap can never
         # certify no matter how many probes confirm it)
@@ -163,5 +164,5 @@ def construct_h2_certified(points: np.ndarray, kernel: Callable,
         opts["max_rank"] = 2 * int(opts.get("max_rank", 64))
         if opts.get("n_samples0"):
             opts["n_samples0"] = 2 * int(opts["n_samples0"])
-    GUARD_COUNTERS["construct/exhausted"] += 1
+    count("guard/construct/exhausted")
     return (*out, cert, max_rounds)

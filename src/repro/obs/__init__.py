@@ -1,19 +1,21 @@
-"""Observability layer: phase tracing, timers, metrics, export (DESIGN.md §8).
+"""Observability layer: scopes, host spans, counters, timers, metrics,
+export (DESIGN.md §8).
 
-``obs.trace`` annotates the hot paths with jit-neutral phase scopes;
-``obs.timers`` measures them (segmented replay / interleaved rounds);
-``obs.metrics`` joins measured time with modeled flops and comm bytes;
-``obs.export`` writes Chrome-trace timelines; ``obs.profile_solve`` is the
-CLI that runs the whole pipeline on the distributed fractional solve.
+``obs.trace`` holds the jit-neutral phase scopes, the host spans and the
+one counter registry; ``obs.timers`` measures phases (segmented replay /
+interleaved rounds); ``obs.metrics`` joins measured time with modeled flops
+and comm bytes; ``obs.export`` writes Chrome-trace timelines;
+``obs.profile_solve`` is the CLI that runs the whole pipeline on the
+distributed fractional solve.
 
 Only ``trace`` is imported eagerly — it is on the hot path of ``core``/
 ``solvers`` and must stay import-light (no numpy/perf dependencies).
 """
-from repro.obs.trace import PHASES_SEEN, annotate, enabled, phase, \
-    set_enabled
+from repro.obs.trace import PHASES_SEEN, REGISTRY, count, counter, \
+    enabled, phase, set_enabled, span
 
-__all__ = ["phase", "annotate", "enabled", "set_enabled", "PHASES_SEEN",
-           "timers", "metrics", "export"]
+__all__ = ["phase", "span", "count", "counter", "enabled", "set_enabled",
+           "PHASES_SEEN", "REGISTRY", "timers", "metrics", "export"]
 
 
 def __getattr__(name):

@@ -3,7 +3,7 @@
 sharded geometric-multigrid V-cycle preconditioner."""
 from .krylov import (PCGState, SolveResult, STATUS_BREAKDOWN,
                      STATUS_INDEFINITE, STATUS_NAN, STATUS_OK,
-                     STATUS_STAGNATION, TRACE_COUNTS, block_cg, gmres,
+                     STATUS_STAGNATION, block_cg, gmres,
                      guards_enabled, pcg, pcg_init, pcg_segment,
                      set_guards_enabled)
 from .mg import GridMG, MGArrays, build_grid_mg, mg_halo_bytes, \
@@ -13,7 +13,7 @@ from .distributed import (krylov_comm_bytes, make_dist_krylov,
                           result_specs)
 
 __all__ = [
-    "SolveResult", "TRACE_COUNTS", "pcg", "block_cg", "gmres",
+    "SolveResult", "pcg", "block_cg", "gmres",
     "PCGState", "pcg_init", "pcg_segment", "pcg_state_specs",
     "STATUS_OK", "STATUS_NAN", "STATUS_INDEFINITE", "STATUS_STAGNATION",
     "STATUS_BREAKDOWN", "guards_enabled", "set_guards_enabled",

@@ -23,8 +23,9 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.dist import (DistH2Data, DistH2Shape, dist_h2_matvec_local,
                              dist_specs, matvec_comm_bytes)
+from repro.obs.trace import count
 
-from .krylov import (TRACE_COUNTS, PCGState, SolveResult, block_cg, gmres,
+from .krylov import (PCGState, SolveResult, block_cg, gmres,
                      pcg, pcg_init, pcg_segment, _norm)
 
 
@@ -122,7 +123,7 @@ def make_dist_krylov(dshape: DistH2Shape, mesh: Mesh, axis,
     bspec = P(axis, None) if multi else P(axis)
 
     def local(d: DistH2Data, b: jax.Array) -> SolveResult:
-        TRACE_COUNTS[f"dist_{method}"] += 1
+        count(f"retrace/dist_{method}")
 
         def apply_a(x):
             xm = x if multi else x[:, None]

@@ -34,13 +34,8 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.obs.trace import phase
+from repro.obs.trace import count, phase
 
-# retrace counters, keyed by program name (test hook — mirrors
-# core/compression.TRACE_COUNTS)
-TRACE_COUNTS = {"pcg": 0, "block_cg": 0, "gmres": 0,
-                "dist_pcg": 0, "dist_block_cg": 0, "dist_gmres": 0,
-                "dist_fractional": 0, "pcg_segment": 0}
 
 # ----------------------------------------------------------------------
 # breakdown-guard status codes (DESIGN.md §11).  The codes ride the
@@ -239,7 +234,7 @@ def pcg_segment(apply_a: Callable, b: jax.Array, state: PCGState,
     the elastic driver's recomputed-residual tripwire covers slow-drift
     cases at segment boundaries).
     """
-    TRACE_COUNTS["pcg_segment"] += 1
+    count("retrace/pcg_segment")
     g = bool(guard) and _GUARD_ENABLED
     m = precond if precond is not None else _identity
     b_norm = _norm(b, axis)
@@ -287,7 +282,7 @@ def pcg(apply_a: Callable, b: jax.Array,
     ``scalar_dtype``: accumulate the dot-product scalars in this dtype
     (the fp64 escalation rung; vector iterates keep ``b``'s dtype).
     """
-    TRACE_COUNTS["pcg"] += 1
+    count("retrace/pcg")
     g = bool(guard) and _GUARD_ENABLED
     sdt = scalar_dtype
     cast = (lambda v: v.astype(b.dtype)) if sdt is not None else \
@@ -382,7 +377,7 @@ def block_cg(apply_a: Callable, b: jax.Array,
     healthy columns keep running — the serving layer retires it through
     the degraded path.  ``scalar_dtype``: see :func:`pcg`.
     """
-    TRACE_COUNTS["block_cg"] += 1
+    count("retrace/block_cg")
     g = bool(guard) and _GUARD_ENABLED
     sdt = scalar_dtype
     cast = (lambda v: v.astype(b.dtype)) if sdt is not None else \
@@ -532,7 +527,7 @@ def gmres(apply_a: Callable, b: jax.Array,
     ``STATUS_STAGNATION`` when the accept-only-improving restart logic
     ended the solve without convergence.
     """
-    TRACE_COUNTS["gmres"] += 1
+    count("retrace/gmres")
     g_on = bool(guard) and _GUARD_ENABLED
     mp = precond if precond is not None else _identity
     n_restarts = max(1, -(-int(maxiter) // int(m)))

@@ -350,7 +350,8 @@ def solver_checks(rng, geometries):
     reassociation can legitimately shift the count by an iteration or
     two — parity there is |delta| <= 2 with a looser solution check.
     """
-    from repro.solvers import TRACE_COUNTS, gmres, make_dist_krylov, pcg
+    from repro.obs import counter
+    from repro.solvers import gmres, make_dist_krylov, pcg
 
     cfg = {"uniform2d": dict(tol=1e-6, slack=0, xerr=1e-4),
            "graded1d": dict(tol=1e-4, slack=2, xerr=5e-3)}
@@ -369,7 +370,7 @@ def solver_checks(rng, geometries):
             ddev = place(mesh_p, dsp, ddp)
             bdev = jax.device_put(b, NamedSharding(mesh_p, P("blk")))
 
-            base = TRACE_COUNTS["dist_pcg"]
+            base = counter("retrace/dist_pcg")
             sv = make_dist_krylov(dsp, mesh_p, "blk", method="pcg",
                                   shift=1.0, tol=tol, maxiter=250)
             rp = sv(ddev, bdev)
@@ -380,7 +381,7 @@ def solver_checks(rng, geometries):
                 (tag, p, int(rp.iters), int(ref_p.iters))
             assert err < xerr, (tag, p, err)
             sv(ddev, 2.0 * bdev)                 # cached: no retrace
-            assert TRACE_COUNTS["dist_pcg"] == base + 1
+            assert counter("retrace/dist_pcg") == base + 1
             print(f"OK solver_pcg_{tag}_p{p}", int(rp.iters), err)
 
             sg = make_dist_krylov(dsp, mesh_p, "blk", method="gmres",
@@ -439,7 +440,7 @@ def fractional_checks():
     matches the dense direct solve."""
     from repro.apps.fractional import (dense_reference_solution, solve,
                                        solve_distributed)
-    from repro.solvers import TRACE_COUNTS
+    from repro.obs import counter
 
     ref = solve(16, h2_tol=1e-7, tol=1e-10)
     u_dense = dense_reference_solution(16)
@@ -453,9 +454,9 @@ def fractional_checks():
               / np.linalg.norm(u_dense))
         assert du < 1e-5, (p, du)
         assert dd < 2e-2, (p, dd)
-        base = TRACE_COUNTS["dist_fractional"]
+        base = counter("retrace/dist_fractional")
         res["parts"]["fn"](*res["placed_args"], res["b"])
-        assert TRACE_COUNTS["dist_fractional"] == base
+        assert counter("retrace/dist_fractional") == base
         if p == 8:
             _assert_callback_free(res["parts"]["fn"], *res["placed_args"],
                                   res["b"])
@@ -754,7 +755,8 @@ def chaos_main():
     # a bf16-payload run triggers the precision-escalation rung — the
     # restart rebuilds the segment with full fp32 halo payloads and the
     # solve converges with a clean final status
-    from repro.guard import GUARD_COUNTERS, reset_guard_counters
+    from repro.guard import reset_guard_counters
+    from repro.obs import counter
     reset_guard_counters()
     with tempfile.TemporaryDirectory() as d:
         res = solve_distributed_elastic(
@@ -763,7 +765,7 @@ def chaos_main():
     assert res["converged"] and res["restarts"] == 1
     assert res["comm_final"] == "halo-plan", res["comm_final"]
     assert res["status"] == 0
-    assert GUARD_COUNTERS["elastic/fp32-comm"] == 1
+    assert counter("guard/elastic/fp32-comm") == 1
     assert du(res) < 1e-5, du(res)
     print("OK chaos_guard_fp32comm", res["iters"], res["comm_final"])
 

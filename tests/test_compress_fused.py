@@ -16,6 +16,7 @@ from repro.core.clustering import regular_grid_points
 from repro.core.construction import construct_h2
 from repro.core.kernels_fn import exponential_kernel
 from repro.core.matvec import h2_matvec
+from repro.obs import counter
 from repro.core.orthogonalize import orthogonalize
 from repro.core.reconstruct import reconstruct_dense
 from repro.core.structure import shape_of
@@ -103,10 +104,10 @@ class TestFixedRankSingleDispatch:
     def test_no_retrace_on_repeat_calls(self):
         shape, data = _setup(p=4)
         tgt = tuple(min(6, k) for k in shape.ranks)
-        base = compression.TRACE_COUNTS["compress_fixed"]
+        base = counter("retrace/compress_fixed")
         cs1, cd1 = compression.compress(shape, data, target_ranks=tgt)
         cs2, cd2 = compression.compress(shape, data, target_ranks=tgt)
-        assert compression.TRACE_COUNTS["compress_fixed"] == base + 1
+        assert counter("retrace/compress_fixed") == base + 1
         assert cs1.ranks == cs2.ranks
         np.testing.assert_array_equal(np.asarray(cd1.u_leaf),
                                       np.asarray(cd2.u_leaf))

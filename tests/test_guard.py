@@ -18,7 +18,7 @@ import jax.numpy as jnp
 
 from test_solvers import hyp, random_spd
 
-from repro.guard import (Certificate, GUARD_COUNTERS, STATUS_BREAKDOWN,
+from repro.guard import (Certificate, STATUS_BREAKDOWN,
                          STATUS_INDEFINITE, STATUS_NAN, STATUS_OK,
                          STATUS_STAGNATION, certify_h2, certify_matvec,
                          check_orthogonal, construct_h2_certified,
@@ -27,6 +27,7 @@ from repro.guard import (Certificate, GUARD_COUNTERS, STATUS_BREAKDOWN,
                          kernel_reference_apply, probe_block,
                          reset_guard_counters, run_with_guards,
                          status_name, validate_h2, worst_status)
+from repro.obs import counter
 from repro.solvers import block_cg, gmres, pcg, set_guards_enabled
 
 
@@ -368,8 +369,8 @@ class TestRunWithGuards:
         ])
         assert out.ok and out.rung == "primary"
         assert not out.recovered
-        assert GUARD_COUNTERS["accept/primary"] == 1
-        assert GUARD_COUNTERS["escalations"] == 0
+        assert counter("guard/accept/primary") == 1
+        assert counter("guard/escalations") == 0
 
     def test_ladder_recovers_indefinite_via_gmres(self):
         """The acceptance drill: a near-indefinite system trips PCG, the
@@ -385,9 +386,9 @@ class TestRunWithGuards:
         assert out.rung == "gmres"
         assert out.attempts[0] == ("pcg", "indefinite")
         assert out.attempts[1] == ("gmres", "ok")
-        assert GUARD_COUNTERS["reject/pcg"] == 1
-        assert GUARD_COUNTERS["accept/gmres"] == 1
-        assert GUARD_COUNTERS["status/indefinite"] == 1
+        assert counter("guard/reject/pcg") == 1
+        assert counter("guard/accept/gmres") == 1
+        assert counter("guard/status/indefinite") == 1
 
     def test_raising_rung_continues_ladder(self):
         def boom():
@@ -401,7 +402,7 @@ class TestRunWithGuards:
         ])
         assert out.ok and out.rung == "good"
         assert out.attempts[0][1].startswith("raised:")
-        assert GUARD_COUNTERS["raise/bad"] == 1
+        assert counter("guard/raise/bad") == 1
 
     def test_exhausted_ladder_reports_not_ok(self):
         a, b = drill_near_singular(lam_min=-0.1, seed=0)
@@ -410,7 +411,7 @@ class TestRunWithGuards:
                                 maxiter=50)),
         ])
         assert not out.ok and not out.recovered
-        assert GUARD_COUNTERS["exhausted"] == 1
+        assert counter("guard/exhausted") == 1
 
     def test_all_raising_reraises(self):
         def boom():
@@ -446,8 +447,8 @@ class TestGuardDrills:
             sketch_opts=drill_rank_starved())
         assert cert.ok, cert.rel_err
         assert rounds > 1          # escalation had real work
-        assert GUARD_COUNTERS["construct/recovered"] == 1
-        assert GUARD_COUNTERS["construct/cert-failed"] == rounds - 1
+        assert counter("guard/construct/recovered") == 1
+        assert counter("guard/construct/cert-failed") == rounds - 1
         # the recovered operator also passes structural validation
         assert validate_h2(shape, data, check_orth=False).ok
 

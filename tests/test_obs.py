@@ -7,6 +7,11 @@ annotated function is byte-identical with tracing enabled and disabled,
 and stays callback-free.  (``IterationTimer`` is the sanctioned exception:
 it DOES add a callback and is therefore opt-in only — asserted here too.)
 
+The host spans and the one counter registry: totals, the disable switch,
+a span's neutrality inside a trace, its place on a profiler trace's
+timeline, the compress tolerance path's host syncs, and the scopes the
+lowered programs carry.
+
 Also covered: the replay timers' env threading, the wire-byte
 normalization factors, PhaseRecord's model join, the Chrome-trace export,
 and the per-phase comm-model decomposition summing exactly to
@@ -129,6 +134,162 @@ def test_iteration_timer_is_not_neutral():
     fn = timer.wrap(lambda x: x * 2.0)
     prims = walk_primitives(jax.make_jaxpr(fn)(jnp.ones(4)).jaxpr, [])
     assert any("callback" in p for p in prims), set(prims)
+
+
+# ---------------------------------------------------------------------------
+# host spans and counters
+# ---------------------------------------------------------------------------
+
+def _span_total(name):
+    return trace.REGISTRY.totals().get(name, (0, 0))
+
+
+def test_span_and_count_totals():
+    trace.REGISTRY.clear("obs-test/")
+    for _ in range(2):
+        with trace.span("obs-test/host"):
+            sum(range(1000))
+    trace.count("obs-test/n")
+    trace.count("obs-test/n", 2)
+    n, ns = _span_total("obs-test/host")
+    assert n == 2 and ns > 0
+    assert trace.counter("obs-test/n") == 3
+    mine = [sp for sp in trace.REGISTRY.recent() if sp[0] == "obs-test/host"]
+    assert len(mine) == 2 and all(s < e for _, s, e in mine)
+    assert sum(e - s for _, s, e in mine) == ns
+    trace.REGISTRY.clear("obs-test/")
+    assert trace.counter("obs-test/n") == 0
+    assert _span_total("obs-test/host") == (0, 0)
+
+
+def test_span_and_count_do_nothing_when_disabled():
+    trace.REGISTRY.clear("obs-test/")
+    trace.set_enabled(False)
+    with trace.span("obs-test/off"):
+        pass
+    trace.count("obs-test/off")
+    assert _span_total("obs-test/off") == (0, 0)
+    assert trace.counter("obs-test/off") == 0
+
+
+def test_registry_memory_is_bounded():
+    reg = trace.Registry(recent=4)
+    for i in range(10):
+        reg.add_span("obs-test/x", i, i + 1)
+    assert reg.totals()["obs-test/x"] == (10, 10)
+    assert [s for _, s, _ in reg.recent()] == [6, 7, 8, 9]
+    assert reg.recorded - len(reg.recent()) == 6
+
+
+def test_span_inside_jit_is_neutral_and_records_nothing():
+    def plain(x):
+        return jnp.sin(x) * 2.0
+
+    def spanned(x):
+        with trace.span("obs-test/traced"):
+            return jnp.sin(x) * 2.0
+
+    x = jnp.ones((8,), jnp.float32)
+    before = _span_total("obs-test/traced")
+    assert _jaxpr_str(spanned, x) == _jaxpr_str(plain, x)
+    jax.jit(spanned)(x).block_until_ready()
+    assert _span_total("obs-test/traced") == before
+
+
+def test_compile_spans_do_not_overlap():
+    inner = jax.jit(lambda x: jnp.cos(x) + 1.0)
+    outer = jax.jit(lambda x: inner(x) * inner(2.0 * x))
+    jax.clear_caches()
+    outer(jnp.ones((5,), jnp.float32)).block_until_ready()
+    comp = sorted((s, e) for n, s, e in trace.REGISTRY.recent()
+                  if n.startswith("compile/"))
+    assert {n for n, _, _ in trace.REGISTRY.recent()} >= {
+        "compile/trace", "compile/lower", "compile/backend"}
+    assert all(b[0] >= a[1] for a, b in zip(comp, comp[1:]))
+
+
+def test_span_lands_on_its_annotation_in_a_profiler_trace(tmp_path):
+    """Registry time = trace time + the session's profile_start_time."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    x = jnp.ones((64,), jnp.float32)
+    with jax.profiler.trace(str(tmp_path)):
+        with trace.span("obs-test/profiled"):
+            jnp.sin(x).block_until_ready()
+    name, start, end = [sp for sp in trace.REGISTRY.recent()
+                        if sp[0] == "obs-test/profiled"][-1]
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
+    pd = ProfileData.from_file(path)
+    zero = [v for pl in pd.planes for k, v in pl.stats
+            if k == "profile_start_time"]
+    events = [ev for pl in pd.planes if pl.name.startswith("/host:")
+              for line in pl.lines for ev in line.events if ev.name == name]
+    assert len(zero) == 1 and len(events) == 1
+    ev = events[0]
+    assert abs(zero[0] + ev.start_ns - start) < 1e6
+    assert abs(zero[0] + ev.start_ns + ev.duration_ns - end) < 1e6
+
+
+def test_tol_compress_counts_its_host_syncs(small_h2):
+    from repro.core.compression import compress
+
+    shape, data, _, _ = small_h2
+    syncs, calls = (trace.counter("compress/host-syncs"),
+                    trace.counter("compress/calls"))
+    picks = _span_total("compress/rank-pick")[0]
+    compress(shape, data, tol=1e-3)
+    # the scale, the leaf rank and one rank per inner level
+    assert trace.counter("compress/host-syncs") - syncs == shape.depth + 2
+    assert trace.counter("compress/calls") - calls == 1
+    assert _span_total("compress/rank-pick")[0] - picks == shape.depth + 2
+    compress(shape, data, target_ranks=tuple(min(4, k)
+                                             for k in shape.ranks))
+    assert trace.counter("compress/host-syncs") - syncs == shape.depth + 2
+
+
+def _lowered_text(fn, *args):
+    jax.clear_caches()
+    return fn.lower(*args).as_text(debug_info=True)
+
+
+def test_lowered_programs_carry_the_new_scopes(small_h2):
+    from repro.apps.fractional import FractionalProblem, make_operator
+    from repro.core import compression as c
+    from repro.core.matvec import h2_matvec
+
+    prob = FractionalProblem(8).build()
+    u = jnp.ones((64,), jnp.float32)
+    text = _lowered_text(jax.jit(make_operator(prob)), u)
+    for scope in ("solve/transpose-in", "solve/transpose-out",
+                  "solve/stencil", "matvec/layout", "hgemv/dense"):
+        assert scope in text, scope
+    assert "hgemv/matvec" not in text and "hgemv/solve" not in text
+
+    shape, data, _, _ = small_h2
+    x = jnp.ones((shape.n, 1), jnp.float32)
+    text = _lowered_text(h2_matvec, shape, data, x)
+    assert "matvec/layout" in text and "hgemv/matvec/layout" not in text
+
+    data, ru, rv = c._orthogonalize_weights(shape, data, "jnp", True)
+    d = shape.depth
+    w, s = c._leaf_factors_jit(ru[d], "jnp")
+    k = shape.ranks[d - 1]
+    steps = [(c._leaf_factors_jit, (ru[d], "jnp")),
+             (c._leaf_apply_jit, (data.u_leaf, w, 3)),
+             (c._inner_factors_jit, (jnp.swapaxes(w, -1, -2),
+                                     data.e[d], ru[d - 1], "jnp")),
+             (c._inner_apply_jit, (jnp.ones((shape.nodes(d) // 2, 2 * k, k)),
+                                   jnp.ones((shape.nodes(d) // 2, 2 * k, k)),
+                                   3, shape.nodes(d)))]
+    for fn, args in steps:
+        assert "compress/truncate" in _lowered_text(fn, *args), fn
+    tgt = tuple(min(4, r) for r in shape.ranks)
+    text = _lowered_text(c._compress_fixed, shape, data, tgt, "jnp", True,
+                         True)
+    assert "compress/truncate" in text and "compress/project-s" in text
+    assert "compress/truncate/compress/truncate" not in text
 
 
 # ---------------------------------------------------------------------------

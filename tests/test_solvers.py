@@ -18,7 +18,8 @@ try:
 except ImportError:
     HAVE_HYPOTHESIS = False
 
-from repro.solvers import (SolveResult, TRACE_COUNTS, block_cg, gmres,
+from repro.obs import counter
+from repro.solvers import (SolveResult, block_cg, gmres,
                            pcg)
 
 
@@ -259,10 +260,10 @@ class TestSingleProgram:
                                   maxiter=50))
         b = jnp.asarray(np.random.default_rng(4).standard_normal(10),
                         jnp.float32)
-        base = TRACE_COUNTS["pcg"]
+        base = counter("retrace/pcg")
         f(b)
         f(2.0 * b)
-        assert TRACE_COUNTS["pcg"] == base + 1
+        assert counter("retrace/pcg") == base + 1
 
     @pytest.mark.parametrize("method", ["pcg", "block_cg", "gmres"])
     def test_jaxpr_is_callback_free(self, method):
