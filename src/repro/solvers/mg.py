@@ -284,8 +284,13 @@ def _smooth_deep(mg: GridMG, a: MGArrays, l: int, u_ext, b_ext, axis):
 
 
 def _restrict(r):
-    return 0.25 * (r[0::2, 0::2] + r[1::2, 0::2] + r[0::2, 1::2]
-                   + r[1::2, 1::2])
+    # full weighting over each 2x2 block, the additions in
+    # ``_restrict_np``'s order.  Strided ``lax.slice``, not
+    # ``r[0::2, 0::2]``: JAX lowers numpy-style strided indexing to an
+    # element-by-element gather, which took most of the V-cycle's device
+    # time on the TPU
+    s = lambda i, j: jax.lax.slice(r, (i, j), r.shape, (2, 2))
+    return 0.25 * (s(0, 0) + s(1, 0) + s(0, 1) + s(1, 1))
 
 
 def _prolong(e):

@@ -283,6 +283,32 @@ class TestSingleProgram:
         assert not any("callback" in p for p in prims), set(prims)
 
 
+class TestVCycleRestriction:
+    """The V-cycle's full-weighting restriction: the NumPy twin's values to
+    the bit, and no element-by-element gather in the lowered program."""
+
+    @pytest.mark.parametrize("shape", [(256, 256), (64, 256), (8, 8)])
+    def test_restrict_bitwise_equals_numpy(self, shape):
+        from repro.solvers.mg import _restrict, _restrict_np
+        r = np.random.default_rng(7).standard_normal(shape).astype(np.float32)
+        got = np.asarray(jax.jit(_restrict)(jnp.asarray(r)))
+        want = _restrict_np(r)
+        assert got.dtype == want.dtype and got.shape == want.shape
+        np.testing.assert_array_equal(got.view(np.uint32),
+                                      want.view(np.uint32))
+
+    def test_vcycle_lowers_without_gather(self):
+        from repro.solvers import build_grid_mg, mg_precond_local
+        n = 64
+        rng = np.random.default_rng(n)
+        mg, arrs = build_grid_mg(1.0 + rng.random((n, n), np.float32),
+                                 rng.random((n, n), np.float32),
+                                 0.5, 2.0 / n, n)
+        text = jax.jit(lambda a, r: mg_precond_local(mg, a, r)).lower(
+            arrs, jnp.zeros((n * n,), jnp.float32)).as_text()
+        assert "stablehlo.gather" not in text
+
+
 @pytest.mark.slow
 class TestFractionalModelProblem:
     def test_preconditioned_never_more_iterations(self):

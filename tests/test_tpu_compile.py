@@ -5,11 +5,14 @@ Nothing runs: each test lowers one kernel at the main path's shapes (leaf
 not attached, and asserts that Mosaic accepted it (a ``tpu_custom_call``
 in the compiled program).  Interpret-mode tests cannot see what the chip's
 compiler refuses: unaligned blocks, contractions Mosaic cannot lower.
+One more compiles the one-chip V-cycle and asserts that the TPU program
+holds no gather.
 """
 import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -84,3 +87,19 @@ def test_batched_svd_compiles(one_chip, n):
                     sharding=one_chip)
     # the Jacobi kernel and its QR polish both stay compiled
     assert text.count("tpu_custom_call") >= 2
+
+
+def test_vcycle_compiles_without_gather(one_chip):
+    # the one-chip V-cycle on the n = 256 grid: its restriction must not
+    # lower to element-by-element gathers (no Pallas kernel here)
+    from repro.solvers import build_grid_mg, mg_precond_local
+    n = 256
+    rng = np.random.default_rng(0)
+    mg, arrs = build_grid_mg(1.0 + rng.random((n, n), np.float32),
+                             rng.random((n, n), np.float32), 0.5, 2.0 / n, n)
+    spec = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+    text = jax.jit(lambda a, r: mg_precond_local(mg, a, r)).lower(
+        jax.tree.map(spec, arrs),
+        jax.ShapeDtypeStruct((n * n,), F32, sharding=one_chip)
+    ).compile().as_text()
+    assert "gather(" not in text
