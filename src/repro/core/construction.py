@@ -4,8 +4,11 @@ Two construction paths share this entry point:
 
 - ``method="cheb"`` (default) — the paper's path: cluster tree -> dual-tree
   traversal -> Chebyshev interpolation for the low-rank blocks, direct
-  kernel evaluation for the dense leaves.  Runs on the host in numpy; the
-  result is packaged as (H2Shape, H2Data-on-device).
+  kernel evaluation for the dense leaves.  Runs on the host in numpy
+  (``construct_h2_host``, whose factors stay host arrays, as a
+  distributed operator needs them: ``dist.distribute_h2`` places each
+  block row on its own device); ``construct_h2`` then moves the whole
+  operator to the default device once, with its marshaling plan.
 - ``method="sketch"`` — the on-device randomized sketching path
   (``repro.sketch``): batched kernel-block sampling + nested-basis
   rangefinder, everything jitted device code.  Requires a jnp-traceable
@@ -14,8 +17,9 @@ Two construction paths share this entry point:
 
 The Chebyshev path's stages are host spans (``repro.obs.span``):
 ``construct/tree``, ``construct/admissibility``, ``construct/bases``,
-``construct/couplings``, ``construct/dense`` and ``construct/plan`` (the
-marshaling plan and the marshaled buffers).
+``construct/couplings``, ``construct/dense`` and, in ``construct_h2``,
+``construct/plan`` (the move to the device, the marshaling plan and the
+marshaled buffers).
 """
 from __future__ import annotations
 
@@ -49,6 +53,36 @@ def construct_h2(points: np.ndarray, kernel: Callable, leaf_size: int,
                                 **(sketch_opts or {}))
     if method != "cheb":
         raise ValueError(f"unknown construction method {method!r}")
+    shape, host, tree, bs = construct_h2_host(points, kernel, leaf_size,
+                                              cheb_p, eta, dtype, min_level)
+    with span("construct/plan"):
+        u_leaf = jnp.asarray(host.u_leaf)
+        e_list = [jnp.asarray(x) for x in host.e]
+        plan = build_coupling_plan(shape.depth, bs.s_rows, bs.s_cols,
+                                   bs.d_rows, bs.d_cols)
+        data = remarshal(H2Data(
+            u_leaf=u_leaf, v_leaf=u_leaf,
+            e=e_list, f=[x for x in e_list],
+            s=[jnp.asarray(x) for x in host.s],
+            s_rows=[jnp.asarray(x) for x in host.s_rows],
+            s_cols=[jnp.asarray(x) for x in host.s_cols],
+            dense=jnp.asarray(host.dense),
+            d_rows=jnp.asarray(host.d_rows),
+            d_cols=jnp.asarray(host.d_cols),
+            plan=plan))
+    return shape, data, tree, bs
+
+
+def construct_h2_host(points: np.ndarray, kernel: Callable, leaf_size: int,
+                      cheb_p: int, eta: float, dtype=jnp.float32,
+                      min_level: int = 1
+                      ) -> Tuple[H2Shape, H2Data, ClusterTree, BlockStructure]:
+    """The Chebyshev construction with every factor a host (numpy) array
+    in ``dtype``: no device holds any of it, and the data has no
+    marshaling plan (``plan=None``).  ``construct_h2`` is this plus one
+    move to the device; ``dist.distribute_h2`` partitions it and places
+    each block row on its own device."""
+    dtype = np.dtype(dtype)
     with span("construct/tree"):
         tree = build_cluster_tree(points, leaf_size)
     with span("construct/admissibility"):
@@ -59,36 +93,30 @@ def construct_h2(points: np.ndarray, kernel: Callable, leaf_size: int,
 
     with span("construct/bases"):
         u_leaf_np, e_np = build_chebyshev_bases(tree, cheb_p)
-        e_list = [jnp.zeros((0, 0, 0), dtype)]
+        e_list = [np.zeros((0, 0, 0), dtype)]
         for l in range(1, depth + 1):
-            e_list.append(jnp.asarray(e_np[l], dtype))
-        u_leaf = jnp.asarray(u_leaf_np, dtype)
+            e_list.append(np.asarray(e_np[l], dtype))
+        u_leaf = np.asarray(u_leaf_np, dtype)
 
     with span("construct/couplings"):
         s_list, sr_list, sc_list = [], [], []
         for l in range(depth + 1):
             rows, cols = bs.s_rows[l], bs.s_cols[l]
             s_np = build_coupling(tree, cheb_p, l, rows, cols, kernel)
-            s_list.append(jnp.asarray(s_np, dtype))
-            sr_list.append(jnp.asarray(rows, jnp.int32))
-            sc_list.append(jnp.asarray(cols, jnp.int32))
+            s_list.append(np.asarray(s_np, dtype))
+            sr_list.append(np.asarray(rows, np.int32))
+            sc_list.append(np.asarray(cols, np.int32))
 
     with span("construct/dense"):
-        dense = jnp.asarray(build_dense(tree, bs.d_rows, bs.d_cols, kernel),
-                            dtype)
+        dense = np.asarray(build_dense(tree, bs.d_rows, bs.d_cols, kernel),
+                           dtype)
 
-    with span("construct/plan"):
-        plan = build_coupling_plan(depth, bs.s_rows, bs.s_cols,
-                                   bs.d_rows, bs.d_cols)
-        data = remarshal(H2Data(
-            u_leaf=u_leaf, v_leaf=u_leaf,
-            e=e_list, f=[x for x in e_list],
-            s=s_list, s_rows=sr_list, s_cols=sc_list,
-            dense=dense,
-            d_rows=jnp.asarray(bs.d_rows, jnp.int32),
-            d_cols=jnp.asarray(bs.d_cols, jnp.int32),
-            plan=plan))
-
+    data = H2Data(u_leaf=u_leaf, v_leaf=u_leaf, e=e_list,
+                  f=[x for x in e_list], s=s_list, s_rows=sr_list,
+                  s_cols=sc_list, dense=dense,
+                  d_rows=np.asarray(bs.d_rows, np.int32),
+                  d_cols=np.asarray(bs.d_cols, np.int32))
+    per_leaf = np.bincount(bs.d_rows, minlength=1 << depth)
     shape = H2Shape(
         n=tree.n, leaf_size=leaf_size, depth=depth,
         ranks=tuple([k] * (depth + 1)),
@@ -96,7 +124,7 @@ def construct_h2(points: np.ndarray, kernel: Callable, leaf_size: int,
         dense_count=int(bs.d_rows.shape[0]),
         symmetric=True,
         row_maxb=bs.row_maxb(), col_maxb=bs.col_maxb(),
-        dense_maxb=int(plan.dblk.shape[0]) >> depth)
+        dense_maxb=int(per_leaf.max()) if per_leaf.size else 0)
     return shape, data, tree, bs
 
 

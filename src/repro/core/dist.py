@@ -25,7 +25,7 @@ Communication modes for the off-diagonal coupling phase (paper §4.1):
     communication/computation overlap.  ``-bf16`` suffixes halve the payload.
 
 The same plans drive the R-factor exchange in ``dist_orthogonalize_local``
-and the projection-map exchange in ``dist_compress_local`` (the node set a
+and the projection-map exchange in ``make_dist_compress`` (the node set a
 remote device references is identical for xhat rows, R factors, and
 projection maps).
 """
@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import types
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -40,10 +41,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.obs.trace import phase
+from repro.obs.trace import count, phase, set_count, span
 
 from . import halo as _halo
 from .halo import HaloPlan, partition_level
+from .compression import truncation_inner_factors, \
+    truncation_leaf_factors, truncation_project
 from .structure import H2Data, H2Shape, build_slot_plan, marshal_blocks
 
 
@@ -202,9 +205,26 @@ def dist_specs(dshape: DistH2Shape, axis) -> DistH2Data:
 # host-side partitioning
 # ---------------------------------------------------------------------------
 
+def _marshal_host(blocks: np.ndarray, blk: np.ndarray, n_rows: int
+                  ) -> np.ndarray:
+    """``structure.marshal_blocks`` on the host (zero padding slots)."""
+    k1, k2 = blocks.shape[-2], blocks.shape[-1]
+    maxb = blk.shape[0] // max(n_rows, 1)
+    padded = np.concatenate([blocks, np.zeros((1, k1, k2), blocks.dtype)])
+    return np.moveaxis(padded[blk].reshape(n_rows, maxb, k1, k2), 1, 2
+                       ).reshape(n_rows, k1, maxb * k2)
+
+
 def partition_h2(shape: H2Shape, data: H2Data, p: int
                  ) -> Tuple[DistH2Shape, DistH2Data]:
-    """Reorganize a single-device H2Data into the block-row layout."""
+    """Reorganize a single-device H2Data into the block-row layout.
+
+    Runs on the host, and every array of the result is a host (numpy)
+    array: nothing lands on a device until the caller places the result
+    (``distribute_h2``), so each shard goes from the host straight to its
+    own device.  ``data``
+    may be a device or a host operator (``construct_h2_host``).
+    """
     lc = int(np.log2(p))
     if (1 << lc) != p:
         raise ValueError("device count must be a power of two")
@@ -250,10 +270,9 @@ def partition_h2(shape: H2Shape, data: H2Data, p: int
     for l in range(lc):
         b_, c_, _, _ = build_slot_plan(np.asarray(data.s_rows[l]),
                                        np.asarray(data.s_cols[l]), 1 << l)
-        pt_blk.append(jnp.asarray(b_))
-        pt_col.append(jnp.asarray(c_))
-        s_top_mar.append(marshal_blocks(jnp.asarray(np.asarray(data.s[l])),
-                                        jnp.asarray(b_), 1 << l))
+        pt_blk.append(b_)
+        pt_col.append(c_)
+        s_top_mar.append(_marshal_host(np.asarray(data.s[l]), b_, 1 << l))
 
     dshape = DistH2Shape(
         n=shape.n, leaf_size=m, depth=depth, ranks=shape.ranks, p=p, lc=lc,
@@ -265,34 +284,43 @@ def partition_h2(shape: H2Shape, data: H2Data, p: int
         br_offsets=tuple(br_offsets), br_caps=tuple(br_caps),
         dense_offsets=ld.offsets, dense_caps=ld.caps)
 
+    u_leaf = np.asarray(data.u_leaf)
     ddata = DistH2Data(
-        u_leaf=jnp.asarray(np.asarray(data.u_leaf)),
-        v_leaf=jnp.asarray(np.asarray(data.v_leaf)),
-        e_br=[jnp.asarray(x) for x in e_br],
-        f_br=[jnp.asarray(x) for x in f_br],
-        s_br=[jnp.asarray(x) for x in s_br],
-        s_br_rows=[jnp.asarray(x) for x in s_br_r],
-        s_br_cols=[jnp.asarray(x) for x in s_br_c],
-        e_top=[jnp.asarray(np.asarray(data.e[l])) if l > 0 else
-               jnp.zeros((0, 0, 0)) for l in range(lc + 1)],
-        f_top=[jnp.asarray(np.asarray(data.f[l])) if l > 0 else
-               jnp.zeros((0, 0, 0)) for l in range(lc + 1)],
-        s_top=[jnp.asarray(np.asarray(data.s[l])) for l in range(lc)],
-        s_top_rows=[jnp.asarray(np.asarray(data.s_rows[l])) for l in range(lc)],
-        s_top_cols=[jnp.asarray(np.asarray(data.s_cols[l])) for l in range(lc)],
-        dense=jnp.asarray(ld.sv), d_rows=jnp.asarray(ld.sr),
-        d_cols=jnp.asarray(ld.sc),
-        pb_blk=[jnp.asarray(x) for x in pb_blk],
-        pb_col=[jnp.asarray(x) for x in pb_col],
-        s_br_mar=[jnp.asarray(x) for x in s_br_mar],
+        u_leaf=u_leaf,
+        v_leaf=u_leaf if data.v_leaf is data.u_leaf else
+        np.asarray(data.v_leaf),
+        e_br=e_br, f_br=f_br,
+        s_br=s_br, s_br_rows=s_br_r, s_br_cols=s_br_c,
+        e_top=[np.asarray(data.e[l]) if l > 0 else
+               np.zeros((0, 0, 0), np.float32) for l in range(lc + 1)],
+        f_top=[np.asarray(data.f[l]) if l > 0 else
+               np.zeros((0, 0, 0), np.float32) for l in range(lc + 1)],
+        s_top=[np.asarray(data.s[l]) for l in range(lc)],
+        s_top_rows=[np.asarray(data.s_rows[l]) for l in range(lc)],
+        s_top_cols=[np.asarray(data.s_cols[l]) for l in range(lc)],
+        dense=ld.sv, d_rows=ld.sr, d_cols=ld.sc,
+        pb_blk=pb_blk, pb_col=pb_col, s_br_mar=s_br_mar,
         pt_blk=pt_blk, pt_col=pt_col, s_top_mar=s_top_mar,
-        pd_col=jnp.asarray(ld.pc),
-        dense_mar=jnp.asarray(ld.sv_mar),
+        pd_col=ld.pc, dense_mar=ld.sv_mar,
         hp_br=hp_br, hp_dense=ld.plan(),
-        s_br_mar_diag=[jnp.asarray(x) for x in s_br_mar_diag],
-        s_br_mar_off=[jnp.asarray(x) for x in s_br_mar_off],
-        dense_mar_diag=jnp.asarray(ld.sv_mar_diag),
-        dense_mar_off=jnp.asarray(ld.sv_mar_off))
+        s_br_mar_diag=s_br_mar_diag, s_br_mar_off=s_br_mar_off,
+        dense_mar_diag=ld.sv_mar_diag, dense_mar_off=ld.sv_mar_off)
+    return dshape, ddata
+
+
+def distribute_h2(shape: H2Shape, data: H2Data, mesh: Mesh, axis="blk"
+                  ) -> Tuple[DistH2Shape, DistH2Data]:
+    """``partition_h2`` over the mesh's ``axis`` and the placement of the
+    result: each block row goes from the host to its own device and the
+    replicated top levels to every device, so no device holds more than
+    its share (host span ``build/partition``).  Pass a host operator
+    (``construct_h2_host``) where one device cannot hold the whole."""
+    with span("build/partition"):
+        dshape, ddata = partition_h2(shape, data, mesh.shape[axis])
+        ddata = jax.device_put(ddata, jax.tree.map(
+            lambda s: NamedSharding(mesh, s), dist_specs(dshape, axis),
+            is_leaf=lambda s: isinstance(s, P)))
+        jax.block_until_ready(ddata)
     return dshape, ddata
 
 
@@ -864,11 +892,21 @@ def make_dist_matvec(dshape: DistH2Shape, mesh: Mesh, axis,
     combined GEMM per level from the landed buffer, "auto" = static flop
     model.  ``hide_flops > 0`` requests the solver-embedded lowering
     (merged single-``all_to_all`` exchange + hide-aware auto).
+
+    Tracing sets the counter ``dist/exchange-bytes`` to the collective
+    bytes one device receives per application (the comm model below).
     """
     specs = dist_specs(dshape, axis)
     xspec = P(axis, nv_axis)
+    bpe = 2 if comm.endswith("-bf16") else 4
 
     def fn(d: DistH2Data, x: jax.Array) -> jax.Array:
+        nv = x.shape[-1]
+        set_count("dist/exchange-bytes",
+                  merged_exchange_bytes(dshape, nv, comm) +
+                  root_gather_bytes(dshape, nv, comm)
+                  if hide_flops and comm.startswith("halo-plan") else
+                  matvec_comm_bytes(dshape, nv, comm, bpe))
         return dist_h2_matvec_local(dshape, d, x, axis, comm, backend,
                                     schedule, hide_flops)
 
@@ -967,11 +1005,6 @@ def dist_orthogonalize_local(dshape: DistH2Shape, d: DistH2Data, axis
         dense_mar_diag=d.dense_mar_diag, dense_mar_off=d.dense_mar_off))
 
 
-def _stack_local(blocks, idx, n_nodes, maxb):
-    from .compression import _stack_blocks
-    return _stack_blocks(blocks, idx, n_nodes, maxb)
-
-
 def _with_remarshaled(dshape: DistH2Shape, d_old: DistH2Data,
                       d_new: DistH2Data) -> DistH2Data:
     """Refresh the marshaled S buffers from rewritten block values.
@@ -1001,22 +1034,17 @@ def _with_remarshaled(dshape: DistH2Shape, d_old: DistH2Data,
                                s_top_mar=s_top_mar)
 
 
-def dist_compress_local(dshape: DistH2Shape, d: DistH2Data,
-                        target_ranks: Sequence[int], axis) -> DistH2Data:
-    """Distributed recompression with static target ranks (symmetric).
-
-    Paper §5: downsweep (batched QR of stacked blocks, no communication below
-    the C-level), upsweep truncation (batched SVD, one gather at the C-level),
-    then coupling projection with a halo exchange for remote column maps.
-    """
-    assert dshape.symmetric
-    depth, lc, p = dshape.depth, dshape.lc, dshape.p
+def _dist_weights(dshape: DistH2Shape, d: DistH2Data, axis):
+    """Weights downsweep of an orthogonalized operator (top replicated,
+    branch local; zero comm): ``(w, w_top)``, the R factors of the own
+    nodes of branch levels lc..depth and of every node of levels 0..lc
+    (``w_top[lc]`` without level lc's couplings, which ``w[lc]`` folds
+    in).  Each node stacks its row's blocks ``S^T`` from the row-marshaled
+    buffers, as ``compression.compression_weights`` does, so padding
+    slots add zero rows and nothing else."""
+    depth, lc = dshape.depth, dshape.lc
     me = jax.lax.axis_index(axis)
     ranks = dshape.ranks
-    tr = list(target_ranks)
-    d = dist_orthogonalize_local(dshape, d, axis)
-
-    # ---- weights downsweep (top replicated, branch local; zero comm) ----
     w_top: Dict[int, jax.Array] = {0: jnp.zeros((1, ranks[0], ranks[0]),
                                                 d.u_leaf.dtype)}
     for l in range(1, lc + 1):
@@ -1025,10 +1053,8 @@ def dist_compress_local(dshape: DistH2Shape, d: DistH2Data,
         rpar = jnp.repeat(w_top[l - 1], 2, axis=0)
         par = jnp.einsum("cij,ckj->cik", rpar, d.e_top[l], precision="highest")
         pieces = [par]
-        if l < lc and dshape.top_counts[l] > 0:
-            st = jnp.swapaxes(d.s_top[l], -1, -2)
-            pieces.append(_stack_local(st, d.s_top_rows[l], nn,
-                                       dshape.row_maxb[l] or 1))
+        if l < lc and dshape.row_maxb[l] > 0:
+            pieces.append(jnp.swapaxes(d.s_top_mar[l], -1, -2))
         stack = jnp.concatenate(pieces, axis=1)
         if stack.shape[1] < kl:
             stack = jnp.concatenate(
@@ -1041,7 +1067,7 @@ def dist_compress_local(dshape: DistH2Shape, d: DistH2Data,
         if lc > 0 else w_top[0]
     w[lc] = own_top
     # redo level lc with the branch coupling blocks folded in
-    if dshape.br_counts[0] > 0:
+    if dshape.row_maxb[lc] > 0:
         nloc = dshape.nodes_local(lc)
         kl = ranks[lc]
         if lc > 0:
@@ -1054,9 +1080,7 @@ def dist_compress_local(dshape: DistH2Shape, d: DistH2Data,
             pieces = [par]
         else:
             pieces = [jnp.zeros((nloc, ranks[0], kl), d.u_leaf.dtype)]
-        st = jnp.swapaxes(d.s_br[0], -1, -2)
-        pieces.append(_stack_local(st, d.s_br_rows[0], nloc,
-                                   max(dshape.br_counts[0], 1)))
+        pieces.append(jnp.swapaxes(d.s_br_mar[0], -1, -2))
         stack = jnp.concatenate(pieces, axis=1)
         if stack.shape[1] < kl:
             stack = jnp.concatenate(
@@ -1070,102 +1094,237 @@ def dist_compress_local(dshape: DistH2Shape, d: DistH2Data,
         rpar = jnp.repeat(w[l - 1], 2, axis=0)
         par = jnp.einsum("cij,ckj->cik", rpar, d.e_br[i], precision="highest")
         pieces = [par]
-        if dshape.br_counts[i] > 0:
-            st = jnp.swapaxes(d.s_br[i], -1, -2)
-            pieces.append(_stack_local(st, d.s_br_rows[i], nloc,
-                                       max(dshape.br_counts[i], 1)))
+        if dshape.row_maxb[l] > 0:
+            pieces.append(jnp.swapaxes(d.s_br_mar[i], -1, -2))
         stack = jnp.concatenate(pieces, axis=1)
         if stack.shape[1] < kl:
             stack = jnp.concatenate(
                 [stack, jnp.zeros((nloc, kl - stack.shape[1], kl),
                                   stack.dtype)], axis=1)
         w[l] = jnp.linalg.qr(stack, mode="r")[..., :kl, :]
+    return w, w_top
 
-    # ---- truncation upsweep: branch local -> gather at C-level -> top ----
-    # the per-branch schedule is the single-device fused upsweep
-    # (compression.truncation_* steps) run inside shard_map
-    from .compression import truncation_inner_factors, \
-        truncation_leaf_factors, truncation_project
-    wq, _ = truncation_leaf_factors(w[depth])
-    rq = min(tr[depth], wq.shape[-1])
-    wk = wq[..., :rq]
-    new_leaf = jnp.einsum("nmk,nkr->nmr", d.u_leaf, wk, precision="highest")
-    pmap_: Dict[int, jax.Array] = {depth: jnp.swapaxes(wk, -1, -2)}
-    new_e_br = [d.e_br[0]] + [None] * (depth - lc)
-    for l in range(depth, lc, -1):
-        stack, g, _ = truncation_inner_factors(pmap_[l], d.e_br[l - lc],
-                                               w[l - 1])
-        rl = stack.shape[1] // 2
-        rp = min(tr[l - 1], g.shape[-1], 2 * rl)
-        gk = g[..., :rp]
-        new_e_br[l - lc] = gk.reshape(2 * stack.shape[0], rl, rp)
-        pmap_[l - 1] = truncation_project(gk, stack)
-    # gather branch-root projections, continue on top
-    p_top: Dict[int, jax.Array] = {
-        lc: jax.lax.all_gather(pmap_[lc], axis, tiled=True)}
-    new_e_top = [d.e_top[0]] + [None] * lc
-    for l in range(lc, 0, -1):
-        stack, g, _ = truncation_inner_factors(p_top[l], d.e_top[l],
-                                               w_top[l - 1])
-        rl = stack.shape[1] // 2
-        rp = min(tr[l - 1], g.shape[-1], 2 * rl)
-        gk = g[..., :rp]
-        new_e_top[l] = gk.reshape(2 * stack.shape[0], rl, rp)
-        p_top[l - 1] = truncation_project(gk, stack)
 
-    # ---- coupling projection (planned exchange for remote column maps;
-    # level lc rides the C-level gather that opened the top sweep) ----
-    s_br_new, s_top_new = [], []
-    for l in range(lc, depth + 1):
-        i = l - lc
-        pl_ = pmap_[l]
-        if l == lc and p > 1:
+# the arrays an orthogonalization or a recompression's coupling step
+# rewrites (the symmetric operator's column tree is its row tree); the
+# index arrays, plans and dense leaves of the result are the input's own
+_ORTHOGONALIZED = ("u_leaf", "e_br", "e_top", "s_br", "s_top")
+_COUPLINGS = ("s_br", "s_top", "s_br_mar", "s_top_mar", "s_br_mar_diag",
+              "s_br_mar_off")
+
+
+def _with_new(d: DistH2Data, new: Dict[str, jax.Array]) -> DistH2Data:
+    d = dataclasses.replace(d, **new)
+    return dataclasses.replace(d, v_leaf=d.u_leaf, f_br=d.e_br, f_top=d.e_top)
+
+
+def _leaf_step(dshape: DistH2Shape, d: DistH2Data, axis):
+    """The sweep's first step: orthogonalization, the weights and the leaf
+    factors, ``(orthogonalized arrays, w_br, w_top, g, s)`` (``w_br`` of
+    levels lc..depth, ``w_top`` of levels 0..lc)."""
+    depth, lc = dshape.depth, dshape.lc
+    d = dist_orthogonalize_local(dshape, d, axis)
+    w, w_top = _dist_weights(dshape, d, axis)
+    g, s = truncation_leaf_factors(w[depth])
+    return ({k: getattr(d, k) for k in _ORTHOGONALIZED},
+            [w[l] for l in range(lc, depth + 1)],
+            [w_top[l] for l in range(lc + 1)], g, s)
+
+
+def _level_step(dshape: DistH2Shape, l: int, cut: int, axis, g, stack,
+                e=None, w=None):
+    """Level ``l``'s truncation at rank ``cut``.  The factors ``g`` of the
+    step below (the leaf factors at l = depth, with ``stack`` the leaf
+    basis) sliced to ``cut`` give the level's new basis (the leaf basis,
+    else the transfers of level l + 1) and its projection map ``P_l``,
+    gathered over the devices at l = lc; then, for l > 0, level l - 1's
+    inner factors ``(stack, g, s)`` from ``P_l``, level l's transfers
+    ``e`` and level l - 1's weights ``w``.  The steps of
+    ``compression.truncate_by_tol``."""
+    gk = g[..., :cut]
+    if l == dshape.depth:
+        basis = jnp.einsum("nmk,nkr->nmr", stack, gk, precision="highest")
+        pl = jnp.swapaxes(gk, -1, -2)
+    else:
+        basis = gk.reshape(2 * stack.shape[0], stack.shape[1] // 2, cut)
+        pl = truncation_project(gk, stack)
+    if l == dshape.lc and dshape.p > 1:
+        pl = jax.lax.all_gather(pl, axis, tiled=True)
+    if l == 0:
+        return basis, pl
+    return (basis, pl) + truncation_inner_factors(pl, e, w)
+
+
+def _couplings_step(dshape: DistH2Shape, d: DistH2Data, p_br, p_top, axis):
+    """The sweep's last step: every coupling projected, ``S' = P_t S
+    P_s^T`` (the planned exchange brings remote column maps; level lc's
+    ride the C-level gather, ``p_top[lc]``), and the marshaled buffers
+    refreshed.  ``p_br``: the maps of levels lc+1..depth, ``p_top`` of
+    levels 0..lc."""
+    depth, lc, p = dshape.depth, dshape.lc, dshape.p
+    nloc = dshape.nodes_local(lc)
+    own = jax.lax.dynamic_slice_in_dim(
+        p_top[lc], jax.lax.axis_index(axis) * nloc, nloc, 0)
+    s_br, s_top = [], []
+    for i, pl in enumerate([own] + list(p_br)):
+        if i == 0 and p > 1:
             pc = jnp.take(p_top[lc], d.s_br_cols[i], axis=0)
         else:
-            buf = _halo.exchange(pl_, d.hp_br[i], dshape.br_offsets[i],
-                                 axis, p) if p > 1 else pl_
+            buf = _halo.exchange(pl, d.hp_br[i], dshape.br_offsets[i],
+                                 axis, p) if p > 1 else pl
             pc = jnp.take(buf, d.hp_br[i].blk_idx, axis=0)
-        pr = jnp.take(pl_, d.s_br_rows[i], axis=0)
-        s_br_new.append(jnp.einsum("brk,bkj,bsj->brs", pr, d.s_br[i], pc,
-                                   precision="highest"))
+        pr = jnp.take(pl, d.s_br_rows[i], axis=0)
+        s_br.append(jnp.einsum("brk,bkj,bsj->brs", pr, d.s_br[i], pc,
+                               precision="highest"))
     for l in range(lc):
         if dshape.top_counts[l] == 0:
-            nb = d.s_top[l].shape[0]
             rnew = p_top[l].shape[1]
-            s_top_new.append(jnp.zeros((nb, rnew, rnew), d.u_leaf.dtype))
+            s_top.append(jnp.zeros((d.s_top[l].shape[0], rnew, rnew),
+                                   d.u_leaf.dtype))
             continue
         pr = jnp.take(p_top[l], d.s_top_rows[l], axis=0)
         pc = jnp.take(p_top[l], d.s_top_cols[l], axis=0)
-        s_top_new.append(jnp.einsum("brk,bkj,bsj->brs", pr, d.s_top[l], pc,
-                                    precision="highest"))
-
-    return _with_remarshaled(dshape, d, DistH2Data(
-        u_leaf=new_leaf, v_leaf=new_leaf,
-        e_br=new_e_br, f_br=new_e_br,
-        s_br=s_br_new, s_br_rows=d.s_br_rows, s_br_cols=d.s_br_cols,
-        e_top=new_e_top, f_top=new_e_top,
-        s_top=s_top_new, s_top_rows=d.s_top_rows, s_top_cols=d.s_top_cols,
-        dense=d.dense, d_rows=d.d_rows, d_cols=d.d_cols,
-        pb_blk=d.pb_blk, pb_col=d.pb_col, s_br_mar=d.s_br_mar,
-        pt_blk=d.pt_blk, pt_col=d.pt_col, s_top_mar=d.s_top_mar,
-        pd_col=d.pd_col, dense_mar=d.dense_mar,
-        hp_br=d.hp_br, hp_dense=d.hp_dense,
-        s_br_mar_diag=d.s_br_mar_diag, s_br_mar_off=d.s_br_mar_off,
-        dense_mar_diag=d.dense_mar_diag, dense_mar_off=d.dense_mar_off))
+        s_top.append(jnp.einsum("brk,bkj,bsj->brs", pr, d.s_top[l], pc,
+                                precision="highest"))
+    new = _with_remarshaled(dshape, d, dataclasses.replace(
+        d, s_br=s_br, s_top=s_top))
+    return {k: getattr(new, k) for k in _COUPLINGS}
 
 
-def make_dist_compress(dshape: DistH2Shape, mesh: Mesh, axis,
-                       target_ranks: Sequence[int]):
+def _sweep(dshape: DistH2Shape, d: DistH2Data, steps, pick
+           ) -> Tuple[Tuple[int, ...], DistH2Data]:
+    """The recompression's single upsweep on block rows (paper §5):
+    ``steps.leaf``, ``steps.level(l, cut)`` from depth to 0, then
+    ``steps.couplings``.  Level l's rank is ``pick(l, s)`` from its
+    singular values ``s``, capped as in ``compression.truncate_by_tol``.
+    ``steps`` holds programs (``make_dist_compress``) or the local steps
+    inside one program (``dist_compress_local``).  Returns the ranks and
+    the recompressed operator."""
+    depth, lc = dshape.depth, dshape.lc
+    orthogonal, w_br, w_top, g, s = steps.leaf(d)
+    d_o = _with_new(d, orthogonal)
+    ranks = list(dshape.ranks)
+    ranks[depth] = min(pick(depth, s), ranks[depth], g.shape[-1])
+    new = {"e_br": list(d_o.e_br), "e_top": list(d_o.e_top)}
+    p_br, p_top = [None] * (depth - lc), [None] * (lc + 1)
+    stack = d_o.u_leaf
+    for l in range(depth, -1, -1):
+        args = (g, stack)
+        if l > 0:
+            args += (d_o.e_br[l - lc] if l > lc else d_o.e_top[l],
+                     w_br[l - 1 - lc] if l > lc else w_top[l - 1])
+        basis, pl, *nxt = steps.level(l, ranks[l])(*args)
+        if l == depth:
+            new["u_leaf"] = basis
+        elif l >= lc:
+            new["e_br"][l + 1 - lc] = basis
+        else:
+            new["e_top"][l + 1] = basis
+        if l > lc:
+            p_br[l - lc - 1] = pl
+        else:
+            p_top[l] = pl
+        if l > 0:
+            stack, g, s = nxt
+            ranks[l - 1] = min(pick(l - 1, s), dshape.ranks[l - 1],
+                               g.shape[-1], 2 * ranks[l])
+    new.update(steps.couplings(d_o, p_br, p_top))
+    return tuple(ranks), _with_new(d, new)
+
+
+def dist_compress_local(dshape: DistH2Shape, d: DistH2Data,
+                        target_ranks: Sequence[int], axis) -> DistH2Data:
+    """The recompression's sweep at static ranks, inside one shard_map
+    program: for lowering on abstract values (``launch/dryrun_h2.py``),
+    where there are no singular values to pick ranks from.  The same
+    steps as ``make_dist_compress``."""
+    assert dshape.symmetric
+    steps = types.SimpleNamespace(
+        leaf=functools.partial(_leaf_step, dshape, axis=axis),
+        level=lambda l, cut: functools.partial(_level_step, dshape, l, cut,
+                                               axis),
+        couplings=functools.partial(_couplings_step, dshape, axis=axis))
+    return _sweep(dshape, d, steps, lambda l, s: target_ranks[l])[1]
+
+
+@jax.jit
+def _most_above(s: jax.Array, thresh: jax.Array) -> jax.Array:
+    """Most singular values above ``thresh`` in any node (rows of ``s``),
+    over every device that holds a part of ``s``."""
+    return jnp.max(jnp.sum(s > thresh, axis=-1))
+
+
+def make_dist_compress(dshape: DistH2Shape, mesh: Mesh, axis, tol: float):
+    """Distributed recompression to the tolerance ``tol`` (symmetric).
+
+    Paper §5 on block rows: the orthogonalization and the weights (batched
+    QR, no communication below the C-level), then one truncation upsweep
+    (batched SVD, one gather at the C-level) and the coupling projection
+    (the planned halo exchange for remote column maps).  The ranks follow
+    the one-device ``compress(tol)`` (``compression.truncate_by_tol``):
+    the scale is the largest leaf singular value on any device, and each
+    level's rank the most singular values above ``tol * scale`` in any
+    node on any device (at least 1, capped as there).  Each step is one
+    program and each SVD runs once: the host picks a level's rank from
+    its singular values between the steps (span ``compress/rank-pick``,
+    counter ``compress/host-syncs``; depth + 2 in all).
+
+    Returns ``run(d) -> (DistH2Shape, DistH2Data)``, the compressed shape
+    and operator; the level steps compile once per level and rank.
+    """
+    assert dshape.symmetric
+    depth, lc = dshape.depth, dshape.lc
     specs = dist_specs(dshape, axis)
 
-    def fn(d: DistH2Data) -> DistH2Data:
-        return dist_compress_local(dshape, d, tuple(target_ranks), axis)
+    def spec(replicated: bool):
+        return P() if replicated else P(axis)
 
-    out_specs = dist_specs(
-        dataclasses.replace(dshape, ranks=tuple(target_ranks)), axis)
-    shmapped = jax.shard_map(fn, mesh=mesh, in_specs=(specs,),
-                             out_specs=out_specs, check_vma=False)
-    return jax.jit(shmapped)
+    def program(fn, in_specs, out_specs):
+        return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                     out_specs=out_specs, check_vma=False))
+
+    w_specs = ([spec(False)] * (depth - lc + 1), [spec(True)] * (lc + 1))
+    leaf = program(functools.partial(_leaf_step, dshape, axis=axis),
+                   (specs,), ({k: getattr(specs, k) for k in _ORTHOGONALIZED},)
+                   + w_specs + (spec(False), spec(False)))
+    couplings = program(
+        functools.partial(_couplings_step, dshape, axis=axis),
+        (specs, [spec(False)] * (depth - lc), [spec(True)] * (lc + 1)),
+        {k: getattr(specs, k) for k in _COUPLINGS})
+    levels: Dict[Tuple[int, int], object] = {}
+
+    def level(l: int, cut: int):
+        if (l, cut) not in levels:
+            ins = (spec(l < lc), spec(l < lc))
+            outs = (spec(l < lc), spec(l <= lc))
+            if l > 0:
+                ins += (spec(l <= lc), spec(l - 1 < lc))
+                outs += (spec(l <= lc),) * 3
+            levels[l, cut] = program(
+                functools.partial(_level_step, dshape, l, cut, axis), ins,
+                outs)
+        return levels[l, cut]
+
+    steps = types.SimpleNamespace(leaf=leaf, level=level,
+                                  couplings=couplings)
+
+    def run(d: DistH2Data) -> Tuple[DistH2Shape, DistH2Data]:
+        count("compress/calls")
+        thresh = []
+
+        def pick(l: int, s: jax.Array) -> int:
+            if not thresh:     # the scale, from the leaf level
+                with span("compress/rank-pick"):
+                    count("compress/host-syncs")
+                    thresh.append(tol * float(jnp.max(s)))
+            with span("compress/rank-pick"):
+                count("compress/host-syncs")
+                return max(int(_most_above(s, thresh[0])), 1)
+
+        ranks, new = _sweep(dshape, d, steps, pick)
+        return dataclasses.replace(dshape, ranks=ranks), new
+
+    return run
 
 
 # ---------------------------------------------------------------------------

@@ -150,13 +150,13 @@ class LevelPartition:
     sv_mar_off: np.ndarray   # [p*n_bnd_cap, k1, maxb_o*k2]
 
     def plan(self) -> HaloPlan:
-        a = jnp.asarray
-        return HaloPlan(send=[a(s) for s in self.send],
-                        comb_idx=a(self.comb_idx),
-                        diag_blk=a(self.diag_blk), diag_col=a(self.diag_col),
-                        bnd_rows=a(self.bnd_rows), rowpos=a(self.rowpos),
-                        off_blk=a(self.off_blk), off_idx=a(self.off_idx),
-                        blk_idx=a(self.blk_idx))
+        """The plan's gather maps, host arrays (placed with the rest of
+        the partitioned operator)."""
+        return HaloPlan(send=list(self.send), comb_idx=self.comb_idx,
+                        diag_blk=self.diag_blk, diag_col=self.diag_col,
+                        bnd_rows=self.bnd_rows, rowpos=self.rowpos,
+                        off_blk=self.off_blk, off_idx=self.off_idx,
+                        blk_idx=self.blk_idx)
 
 
 def build_send_lists(rows: np.ndarray, cols: np.ndarray, p: int, shift: int

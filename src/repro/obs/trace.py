@@ -11,8 +11,9 @@ which is the default.  ``tests/test_obs.py`` and the dist worker assert
 ``str(jax.make_jaxpr(...))`` equality enabled-vs-disabled.  Every ``phase``
 entered during a trace is recorded in ``PHASES_SEEN``.
 
-Host side: ``span("construct/tree")`` times a block of host code and
-``count("compress/host-syncs")`` adds to a named counter, both into one
+Host side: ``span("construct/tree")`` times a block of host code,
+``count("compress/host-syncs")`` adds to a named counter and
+``set_count("dist/exchange-bytes", n)`` sets one, all into one
 process-wide ``REGISTRY``:
 
 - a span opens a ``TraceAnnotation`` of its name, so it sits on the host
@@ -117,6 +118,10 @@ class Registry:
         with self._lock:
             self.counts[name] += n
 
+    def set_count(self, name: str, n: int) -> None:
+        with self._lock:
+            self.counts[name] = n
+
     def totals(self) -> Dict[str, Tuple[int, int]]:
         """Per span name: (spans, total nanoseconds)."""
         with self._lock:
@@ -180,6 +185,13 @@ def count(name: str, n: int = 1) -> None:
     """Add ``n`` to the counter ``name``."""
     if _ENABLED:
         REGISTRY.count(name, n)
+
+
+def set_count(name: str, n: int) -> None:
+    """Set the counter ``name`` to ``n``: a quantity fixed when a program
+    is built (bytes per application), not a tally."""
+    if _ENABLED:
+        REGISTRY.set_count(name, n)
 
 
 def counter(name: str) -> int:
