@@ -34,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.clustering import build_cluster_tree
+from repro.core.clustering import bit_transpose, build_cluster_tree
 from repro.core.construction import construct_h2
 from repro.core.compression import compress
 from repro.core.dist import (DistH2Data, DistH2Shape, dist_h2_matvec_local,
@@ -183,22 +183,38 @@ def apply_c(u: jax.Array, kappa: jax.Array, h: float) -> jax.Array:
     return -lap / (h * h)
 
 
+def grid_tree_maps(perm, unperm) -> Tuple[Callable, Callable]:
+    """The maps x -> x[perm] (grid to tree order) and x -> x[unperm] (its
+    inverse) on vectors: one reshape-transpose each where ``perm`` permutes
+    index bits (``bit_transpose``: a regular grid), element gathers
+    otherwise.  Counts ``solve/perm-transpose`` or ``solve/perm-gather``."""
+    plan = bit_transpose(perm)
+    if plan is None:
+        count("solve/perm-gather")
+        perm_j, unperm_j = jnp.asarray(perm), jnp.asarray(unperm)
+        return lambda x: x[perm_j], lambda x: x[unperm_j]
+    count("solve/perm-transpose")
+    shape, axes = plan
+    tree_shape = tuple(shape[a] for a in axes)
+    back = tuple(int(a) for a in np.argsort(axes))
+    return (lambda x: x.reshape(shape).transpose(axes).reshape(-1),
+            lambda x: x.reshape(tree_shape).transpose(back).reshape(-1))
+
+
 def make_operator(prob: Dict) -> Callable[[jax.Array], jax.Array]:
     """A u = h^2 (D + K + C) u; u in grid order [N]."""
     shape, data = prob["shape"], prob["data"]
-    perm, unperm = prob["perm"], prob["unperm"]
     d_diag, kappa = prob["d_diag"], prob["kappa"]
     gamma, h, n = prob["gamma"], prob["h"], prob["n"]
-    perm_j = jnp.asarray(perm)
-    unperm_j = jnp.asarray(unperm)
+    to_tree, to_grid = grid_tree_maps(prob["perm"], prob["unperm"])
 
     def apply_a(u: jax.Array) -> jax.Array:
         # the phases _dist_apply_a gives the same work
         with phase("solve/transpose-in"):
-            ut = u[perm_j][:, None]
+            ut = to_tree(u)[:, None]
         kut = h2_matvec(shape, data, ut)
         with phase("solve/transpose-out"):
-            ku = kut[:, 0][unperm_j]
+            ku = to_grid(kut[:, 0])
         with phase("solve/stencil"):
             cu = apply_c(u.reshape(n, n), kappa, h).ravel()
             return (h * h) * (d_diag * u + ku + gamma * cu)

@@ -12,7 +12,7 @@ batch.
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple
+from typing import Optional, Tuple
 
 import numpy as np
 
@@ -97,6 +97,48 @@ def build_cluster_tree(points: np.ndarray, leaf_size: int) -> ClusterTree:
         box_max.append(resh.max(axis=1))
     return ClusterTree(points=pts, perm=perm, depth=depth, leaf_size=leaf_size,
                        box_min=tuple(box_min), box_max=tuple(box_max))
+
+
+def bit_transpose(perm) -> Optional[Tuple[Tuple[int, ...], Tuple[int, ...]]]:
+    """``(shape, axes)`` with ``x[perm] == x.reshape(shape).transpose(axes)
+    .reshape(-1)`` when ``perm`` permutes the bits of the index, else None.
+
+    On a regular ``2^a x 2^a`` grid the KD tree's median splits halve one
+    grid axis at a time, so its order (``ClusterTree.perm``) takes each
+    tree-position bit ``k`` from one grid-index bit ``src[k]``.  Runs of
+    bits that stay adjacent and in order become one axis, so the
+    permutation is one transpose of a few axes and no element gather.
+    None where ``perm`` is not concrete, ``N`` is not a power of two or the
+    order is no bit permutation (scattered points).
+    """
+    try:
+        perm = np.asarray(perm)
+    except TypeError:                   # a tracer: nothing to read here
+        return None
+    n = perm.shape[0]
+    b = n.bit_length() - 1
+    if n < 2 or n != 1 << b:
+        return None
+    step = perm[1 << np.arange(b)]      # where each tree bit alone lands
+    if np.any(step <= 0):
+        return None
+    src = [int(s).bit_length() - 1 for s in step]
+    rebuilt = np.zeros(n, np.int64)
+    pos = np.arange(n)
+    for k, s in enumerate(src):
+        rebuilt |= ((pos >> k) & 1) << s
+    if not np.array_equal(rebuilt, perm):
+        return None
+    # row-major: axis j of (2,)*b is bit b-1-j, on either side
+    runs: list = []
+    for a in (b - 1 - src[b - 1 - j] for j in range(b)):
+        if runs and runs[-1][1] == a:
+            runs[-1][1] += 1
+        else:
+            runs.append([a, a + 1])
+    order = sorted(range(len(runs)), key=lambda r: runs[r][0])
+    shape = tuple(1 << (runs[r][1] - runs[r][0]) for r in order)
+    return shape, tuple(int(i) for i in np.argsort(order))
 
 
 def regular_grid_points(side: int, dim: int, lo: float = 0.0,

@@ -31,6 +31,76 @@ class TestFractional:
         assert with_pre["iters"] < without["iters"], \
             (with_pre["iters"], without["iters"])
 
+    @staticmethod
+    def _grid_perm(n):
+        from repro.apps.fractional import interior_grid
+        from repro.core.clustering import build_cluster_tree
+        leaf = 16 if n <= 32 else 64                 # as the program picks
+        perm = build_cluster_tree(interior_grid(n), leaf).perm
+        unperm = np.empty_like(perm)
+        unperm[perm] = np.arange(perm.size)
+        return perm, unperm
+
+    @pytest.mark.parametrize("n", [32, 64, 256])
+    def test_grid_tree_maps_are_transposes_equal_to_gathers(self, n):
+        from repro.apps.fractional import grid_tree_maps
+        from repro.core.clustering import bit_transpose
+        from repro.obs.trace import counter
+        perm, unperm = self._grid_perm(n)
+        assert bit_transpose(perm) is not None
+        before = counter("solve/perm-transpose")
+        to_tree, to_grid = grid_tree_maps(perm, unperm)
+        assert counter("solve/perm-transpose") - before == 1
+        rng = np.random.default_rng(n)
+        u, v = (rng.standard_normal(n * n).astype(np.float32)
+                for _ in range(2))
+        np.testing.assert_array_equal(
+            np.asarray(jax.jit(to_tree)(jnp.asarray(u))), u[perm])
+        np.testing.assert_array_equal(
+            np.asarray(jax.jit(to_grid)(jnp.asarray(v))), v[unperm])
+        text = jax.jit(to_tree).lower(jnp.asarray(u)).as_text()
+        assert "gather" not in text
+
+    @pytest.mark.parametrize("n", [8, 64])
+    def test_grid_tree_maps_gather_where_no_bit_permutation(self, n):
+        from repro.apps.fractional import grid_tree_maps
+        from repro.core.clustering import bit_transpose
+        from repro.obs.trace import counter
+        rng = np.random.default_rng(n)
+        perm = np.concatenate([[0], 1 + rng.permutation(n * n - 1)])
+        unperm = np.empty_like(perm)
+        unperm[perm] = np.arange(perm.size)
+        assert bit_transpose(perm) is None
+        assert bit_transpose(perm[:-1]) is None      # N not a power of two
+        seen = []                                    # a tracer: not concrete
+        jax.make_jaxpr(lambda p: seen.append(bit_transpose(p)) or p)(
+            jnp.asarray(self._grid_perm(32)[0]))
+        assert seen == [None]
+        counts = [counter(k) for k in ("solve/perm-gather",
+                                       "solve/perm-transpose")]
+        to_tree, to_grid = grid_tree_maps(perm, unperm)
+        assert [counter(k) for k in ("solve/perm-gather",
+                                     "solve/perm-transpose")] == \
+            [counts[0] + 1, counts[1]]
+        u = rng.standard_normal(n * n).astype(np.float32)
+        np.testing.assert_array_equal(
+            np.asarray(jax.jit(to_tree)(jnp.asarray(u))), u[perm])
+        np.testing.assert_array_equal(
+            np.asarray(jax.jit(to_grid)(jnp.asarray(u))), u[unperm])
+
+    def test_operator_equals_its_gather_form_bitwise(self, monkeypatch):
+        import repro.apps.fractional as frac
+        from repro.obs.trace import counter
+        prob = frac.FractionalProblem(32).build()
+        u = jnp.asarray(np.random.default_rng(3).standard_normal(32 * 32),
+                        jnp.float32)
+        before = counter("solve/perm-transpose")
+        got = jax.jit(frac.make_operator(prob))(u)
+        assert counter("solve/perm-transpose") - before == 1
+        monkeypatch.setattr(frac, "bit_transpose", lambda perm: None)
+        want = jax.jit(frac.make_operator(prob))(u)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
 
 class TestTrainEndToEnd:
     def test_loss_drops_and_restart_works(self):

@@ -5,8 +5,8 @@ Nothing runs: each test lowers one kernel at the main path's shapes (leaf
 not attached, and asserts that Mosaic accepted it (a ``tpu_custom_call``
 in the compiled program).  Interpret-mode tests cannot see what the chip's
 compiler refuses: unaligned blocks, contractions Mosaic cannot lower.
-One more compiles the one-chip V-cycle and asserts that the TPU program
-holds no gather.
+Two more compile the one-chip V-cycle and the fractional operator's
+grid<->tree permutations and assert that the TPU program holds no gather.
 """
 import os
 
@@ -103,3 +103,18 @@ def test_vcycle_compiles_without_gather(one_chip):
         jax.ShapeDtypeStruct((n * n,), F32, sharding=one_chip)
     ).compile().as_text()
     assert "gather(" not in text
+
+
+@pytest.mark.parametrize("direction", [0, 1])
+def test_grid_tree_maps_compile_without_gather(one_chip, direction):
+    # the n = 256 operator's grid -> tree (0) and tree -> grid (1) maps:
+    # one transpose each, not an element-by-element gather
+    from repro.apps.fractional import grid_tree_maps, interior_grid
+    from repro.core.clustering import build_cluster_tree
+    n = 256
+    perm = build_cluster_tree(interior_grid(n), 64).perm
+    fn = grid_tree_maps(perm, np.argsort(perm))[direction]
+    text = jax.jit(fn).lower(
+        jax.ShapeDtypeStruct((n * n,), F32, sharding=one_chip)
+    ).compile().as_text()
+    assert "gather(" not in text and "copy(" in text
